@@ -34,21 +34,6 @@ def run(mode="quick"):
     emit("kernel.ecoscan.ref", t_ref * 1e6, f"B={B};P={P};CAP={CAP}")
     emit("kernel.ecoscan.pallas_interpret", t_pal * 1e6, "correctness-mode")
 
-    # before/after: the seed kernel shape (one probe per grid step, O(k*M)
-    # fori_loop argmin merge) vs the tiled sort-based merge. Interpret-mode
-    # numbers are correctness-grade; on TPU the argmin loop serializes k
-    # full-vector reductions per probe while the sort is one lane-parallel
-    # sort network per tile of probes.
-    from repro.kernels.ecoscan import ecoscan as _eco
-    t_argmin = _time(_eco, q, data, lens, probes, merge="argmin",
-                     probe_tile=1)
-    t_sort = _time(_eco, q, data, lens, probes, merge="sort")
-    emit("kernel.ecoscan.merge_argmin", t_argmin * 1e6,
-         "before: per-probe fori_loop argmin merge")
-    emit("kernel.ecoscan.merge_sort", t_sort * 1e6,
-         f"after: tiled sort_key_val merge;"
-         f"speedup={t_argmin / t_sort:.2f}x")
-
     # fused on-device route->scan vs host-routed two-step
     cent = jax.random.normal(jax.random.PRNGKey(7), (NC, d))
 

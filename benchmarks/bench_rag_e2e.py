@@ -11,10 +11,11 @@ import time
 import numpy as np
 
 from benchmarks.common import emit
+from repro.configs import get_reduced
 from repro.data.synthetic import make_qa_corpus
 from repro.serving.embedder import HashEmbedder
 from repro.serving.rag import PIPELINES, SLM_SPEEDS, answer_in_context
-from repro.serving.slm import ReducedSLM
+from repro.serving.slm import SLM
 
 STYLES = {"SQuAD-like": "squad", "HotpotQA-like": "hotpot",
           "TriviaQA-like": "trivia"}
@@ -25,7 +26,7 @@ def run(mode="quick"):
     # Real-generation TTFT reference: Engine prefill + first token on the
     # reduced on-device sLM (one shared instance -> one compile), reported
     # beside the analytical Table-6 ttft estimate on every row.
-    slm_real = ReducedSLM()
+    slm_real = SLM(get_reduced("qwen25_0_5b"))
     slm_real.warmup()
     # measured once per (style, pipeline): the real engine/prompts are
     # identical for every Table-6 slm row, only the analytical column

@@ -30,6 +30,8 @@ def main() -> None:
     ap.add_argument("--mode", default="quick", choices=["quick", "full"])
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name, module in SUITES:

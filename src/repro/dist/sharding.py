@@ -23,8 +23,8 @@ replicated). With no mesh installed — the 1-device CPU test environment —
 on the execution environment.
 
 The mesh itself is ambient state installed with `use_mesh(mesh)`; only the
-launchers touch it. `shard_map` wraps the moving jax API (`check_vma` vs
-`check_rep`) so model code is pinned to one spelling.
+launchers touch it, and they build it with `Auto` axes
+(`launch/mesh.py`), the only kind `with_sharding_constraint` accepts.
 """
 from __future__ import annotations
 
@@ -179,15 +179,7 @@ def shard(x, *axes: Optional[str]):
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """Version-stable `shard_map` (jax renamed check_rep -> check_vma and
-    moved it out of jax.experimental; pin one spelling here)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
+    """`jax.shard_map` with its replication check spelled once
+    (`check_vma`) for model code."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -6,6 +6,8 @@ and searches its small graph. The TPU analogue: cluster blocks live in HBM
 drives the BlockSpec index_map so only the probed clusters' blocks are
 DMA'd into VMEM; distances for the whole (padded) cluster are one MXU
 matmul; a running top-k merge lives in the revisited output block.
+The merge is an unrolled masked-min selection (`_select_topk`): Mosaic
+lowers neither `sort` nor a store at a traced position inside a kernel.
 
 Grid: (B, T) — T probe *tiles* per query (PROBE_TILE clusters DMA'd and
 scanned per step), sequential on a TPU core, so the output block for query
@@ -31,51 +33,47 @@ from repro.kernels.ref import NEG
 DEFAULT_PROBE_TILE = 4
 
 
-def _merge_topk_sort(cand_d, cand_i, out_d_ref, out_i_ref, k: int):
-    """Sort-based merge: concat the running top-k with the new candidates
-    ([1, M]) and take the k smallest in one stable sort_key_val (ties keep
-    flat candidate order, matching lax.top_k in the reference)."""
-    all_d = jnp.concatenate([out_d_ref[...], cand_d], axis=1)   # [1, K+M]
-    all_i = jnp.concatenate([out_i_ref[...], cand_i], axis=1)
-    sd, si = jax.lax.sort_key_val(all_d, all_i, dimension=1)
-    out_d_ref[...] = jax.lax.slice_in_dim(sd, 0, k, axis=1)
-    out_i_ref[...] = jax.lax.slice_in_dim(si, 0, k, axis=1)
+def _select_topk(rows_d, rows_i, k: int):
+    """Stable k-smallest over the concatenation of `rows_d` ([1, n] rows,
+    in flat candidate order) with their ids `rows_i`.
 
-
-def _merge_topk_argmin(cand_d, cand_i, out_d_ref, out_i_ref, k: int):
-    """Legacy O(k·M) sequential-argmin merge — kept for the before/after
-    microbenchmark (bench_kernels.py) and as a lowering fallback."""
-    cur_d = out_d_ref[...]
-    cur_i = out_i_ref[...]
-    all_d = jnp.concatenate([cur_d, cand_d], axis=1)   # [1, K+M]
-    all_i = jnp.concatenate([cur_i, cand_i], axis=1)
-
-    def body(j, carry):
-        ad, ai, od, oi = carry
-        pos = jnp.argmin(ad[0])
-        dval = ad[0, pos]
-        # an exhausted (all-sentinel) pool re-selects position 0, whose id
-        # slot holds an already-picked real id — emit -1 for sentinels
-        ival = jnp.where(dval >= NEG, jnp.int32(-1), ai[0, pos])
-        od = jax.lax.dynamic_update_slice(od, dval[None, None], (0, j))
-        oi = jax.lax.dynamic_update_slice(oi, ival[None, None], (0, j))
-        ad = ad.at[0, pos].set(NEG)
-        return ad, ai, od, oi
-
-    od = jnp.zeros((1, k), jnp.float32)
-    oi = jnp.zeros((1, k), jnp.int32)
-    _, _, od, oi = jax.lax.fori_loop(0, k, body, (all_d, all_i, od, oi))
-    out_d_ref[...] = od
-    out_i_ref[...] = oi
-
-
-_MERGES = {"sort": _merge_topk_sort, "argmin": _merge_topk_argmin}
+    Built only from what Mosaic lowers: row reductions, `broadcasted_iota`
+    and `where`, unrolled over the static `k`. Step j takes the global
+    minimum, resolves ties to the first row holding it and the lowest
+    position in that row (flat order, matching `lax.top_k` in the
+    reference), writes it to output slot j and masks it with +inf. The
+    rows are never concatenated, so any row length lowers. Sentinel (NEG)
+    candidates carry id -1 and surface only after every real one."""
+    iotas = [jax.lax.broadcasted_iota(jnp.int32, r.shape, 1) for r in rows_d]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    out_d = jnp.full((1, k), NEG, jnp.float32)
+    out_i = jnp.full((1, k), -1, jnp.int32)
+    big = jnp.int32(2 ** 30)
+    for j in range(k):
+        mins = [jnp.min(r, axis=1, keepdims=True) for r in rows_d]
+        m = functools.reduce(jnp.minimum, mins)                  # [1, 1]
+        taken = jnp.zeros((1, 1), jnp.bool_)
+        sel = jnp.full((1, 1), -1, jnp.int32)
+        nxt = []
+        for r, ids, iota, rmin in zip(rows_d, rows_i, iotas, mins):
+            here = (rmin == m) & ~taken
+            pos = jnp.min(jnp.where(r == m, iota, big), axis=1,
+                          keepdims=True)
+            hit = here & (iota == pos)
+            got = jnp.sum(jnp.where(hit, ids, 0), axis=1, keepdims=True)
+            sel = jnp.where(here, got, sel)
+            nxt.append(jnp.where(hit, jnp.inf, r))
+            taken = taken | here
+        rows_d = nxt
+        out_d = jnp.where(slot == j, m, out_d)
+        out_i = jnp.where(slot == j, sel, out_i)
+    return out_d, out_i
 
 
 def _kernel(probe_ref, lens_ref, bmap_ref, q_ref, *refs, k: int, cap: int,
-            pt: int, merge: str):
+            pt: int):
     data_refs = refs[:pt]                           # pt x [1, CAP, d]
-    out_d_ref, out_i_ref = refs[pt], refs[pt + 1]
+    out_d_ref, out_i_ref = refs[pt], refs[pt + 1]   # [1, k] each
     t = pl.program_id(1)
 
     @pl.when(t == 0)
@@ -86,8 +84,8 @@ def _kernel(probe_ref, lens_ref, bmap_ref, q_ref, *refs, k: int, cap: int,
     b = pl.program_id(0)
     q = q_ref[...]                                  # [1, d]
     qq = jnp.sum(q * q)
-    cand_d = []
-    cand_i = []
+    cand_d = [out_d_ref[...]]                       # running top-k first:
+    cand_i = [out_i_ref[...]]                       # it precedes in flat order
     for j in range(pt):
         cid = probe_ref[b, t * pt + j]
         blk = bmap_ref[jnp.maximum(cid, 0)]         # cluster -> scan block
@@ -95,16 +93,17 @@ def _kernel(probe_ref, lens_ref, bmap_ref, q_ref, *refs, k: int, cap: int,
         x = data_refs[j][0]                         # [CAP, d]
         # L2 distance via matmul on the MXU: ||x||^2 - 2 x.q + ||q||^2
         xx = jnp.sum(x * x, axis=1, keepdims=True)  # [CAP, 1]
+        # full f32 passes: a one-pass bf16 product would reorder near
+        # neighbours against the exact reference
         xq = jax.lax.dot_general(x, q, (((1,), (1,)), ((), ())),
+                                 precision=jax.lax.Precision.HIGHEST,
                                  preferred_element_type=jnp.float32)
         dist = (xx - 2.0 * xq).T + qq               # [1, CAP]
         slot = jax.lax.broadcasted_iota(jnp.int32, (1, cap), 1)
         valid = (slot < lens_ref[safe]) & (cid >= 0) & (blk >= 0)
         cand_d.append(jnp.where(valid, dist, NEG))
         cand_i.append(jnp.where(valid, safe * cap + slot, -1))
-    cand_d = cand_d[0] if pt == 1 else jnp.concatenate(cand_d, axis=1)
-    cand_i = cand_i[0] if pt == 1 else jnp.concatenate(cand_i, axis=1)
-    _MERGES[merge](cand_d, cand_i, out_d_ref, out_i_ref, k)
+    out_d_ref[...], out_i_ref[...] = _select_topk(cand_d, cand_i, k)
 
 
 def _data_index(b, t, pr, ln, bm, *, j, pt):
@@ -114,9 +113,9 @@ def _data_index(b, t, pr, ln, bm, *, j, pt):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("k", "interpret", "merge", "probe_tile"))
-def ecoscan(q, data, lens, probe_ids, k: int = 10, interpret: bool = True,
-            merge: str = "sort", probe_tile: int | None = None,
+                   static_argnames=("k", "interpret", "probe_tile"))
+def ecoscan(q, data, lens, probe_ids, k: int = 10,
+            interpret: bool | None = None, probe_tile: int | None = None,
             block_map=None):
     """q: [B, d] f32; data: [R, CAP, d] f32; lens: [R] i32;
     probe_ids: [B, P] i32 (ids < 0 are skipped padding).
@@ -128,7 +127,13 @@ def ecoscan(q, data, lens, probe_ids, k: int = 10, interpret: bool = True,
     row block_map[c]; entries < 0 mask the cluster entirely (its
     candidates never surface). Identity when omitted. This is what lets a
     tiered index scan an arbitrary hot subset plus a per-batch gathered
-    cold scratch through the exact same kernel math (DESIGN.md §14)."""
+    cold scratch through the exact same kernel math (DESIGN.md §14).
+
+    `interpret=None` compiles with Mosaic on a TPU and interprets
+    elsewhere (`ops.default_interpret`)."""
+    if interpret is None:
+        from repro.kernels.ops import default_interpret
+        interpret = default_interpret()
     B, d = q.shape
     R, CAP, _ = data.shape
     P = probe_ids.shape[1]
@@ -146,32 +151,36 @@ def ecoscan(q, data, lens, probe_ids, k: int = 10, interpret: bool = True,
     if block_map is None:
         block_map = jnp.arange(R, dtype=jnp.int32)
 
+    # q and the outputs ride as [B, 1, .] with the batch dim squeezed out
+    # of the block: a block's last two dims must equal the array's (or be
+    # (8, 128)-aligned), which a (1, d) block over [B, d] is not for B > 1
+    def row(n):
+        return pl.BlockSpec((pl.Squeezed(), 1, n),
+                            lambda b, t, pr, ln, bm: (b, 0, 0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,                      # probe_ids, lens, bmap
         grid=(B, T),
         in_specs=[
-            pl.BlockSpec((1, d), lambda b, t, pr, ln, bm: (b, 0)),
+            row(d),
             *[pl.BlockSpec((1, CAP, d),
                            functools.partial(_data_index, j=j, pt=pt))
               for j in range(pt)],
         ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda b, t, pr, ln, bm: (b, 0)),
-            pl.BlockSpec((1, k), lambda b, t, pr, ln, bm: (b, 0)),
-        ],
+        out_specs=[row(k), row(k)],
     )
     kern = pl.pallas_call(
-        functools.partial(_kernel, k=k, cap=CAP, pt=pt, merge=merge),
+        functools.partial(_kernel, k=k, cap=CAP, pt=pt),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, k), jnp.float32),
-                   jax.ShapeDtypeStruct((B, k), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((B, 1, k), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, k), jnp.int32)],
         interpret=interpret,
     )
     data = data.astype(jnp.float32)
     out_d, out_i = kern(probe_ids, lens.astype(jnp.int32),
                         block_map.astype(jnp.int32),
-                        q.astype(jnp.float32), *([data] * pt))
-    return out_d, out_i
+                        q.astype(jnp.float32)[:, None, :], *([data] * pt))
+    return out_d[:, 0], out_i[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("n_probe",))
@@ -190,10 +199,10 @@ def route_topk(q, centroids, n_probe: int):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_probe", "k", "interpret", "merge",
+                   static_argnames=("n_probe", "k", "interpret",
                                     "probe_tile"))
 def route_and_scan(q, centroids, data, lens, n_probe: int = 4, k: int = 10,
-                   interpret: bool = True, merge: str = "sort",
+                   interpret: bool | None = None,
                    probe_tile: int | None = None):
     """Fused route->scan: centroid routing (one MXU matmul + lax.top_k) and
     the ecoscan kernel inside a single jit — no host round-trip between
@@ -203,5 +212,5 @@ def route_and_scan(q, centroids, data, lens, n_probe: int = 4, k: int = 10,
     Returns (dists [B, k], slots [B, k], probes [B, n_probe])."""
     probes = route_topk(q, centroids, n_probe)
     dists, slots = ecoscan(q, data, lens, probes, k=k, interpret=interpret,
-                           merge=merge, probe_tile=probe_tile)
+                           probe_tile=probe_tile)
     return dists, slots, probes

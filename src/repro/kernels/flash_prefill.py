@@ -76,8 +76,14 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "window", "tq", "ts",
                                              "interpret"))
 def flash_prefill(q, k, v, *, causal: bool = True, window=None,
-                  tq: int = 128, ts: int = 128, interpret: bool = True):
-    """q,k,v: [B, H, S, dh] (kv pre-expanded to H). Returns [B, H, S, dh]."""
+                  tq: int = 128, ts: int = 128,
+                  interpret: bool | None = None):
+    """q,k,v: [B, H, S, dh] (kv pre-expanded to H). Returns [B, H, S, dh].
+    interpret=None resolves backend-aware (compiled on TPU, interpret
+    elsewhere)."""
+    if interpret is None:
+        from repro.kernels.ops import default_interpret
+        interpret = default_interpret()
     B, H, S, dh = q.shape
     import math
     qf = q.reshape(B * H, S, dh)

@@ -27,8 +27,14 @@ def _kernel(x_ref, c_ref, a_ref, d_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def kmeans_assign(x, centroids, tile: int = 512, interpret: bool = True):
-    """x: [N, d]; centroids: [NC, d] -> (assign [N] i32, sqdist [N] f32)."""
+def kmeans_assign(x, centroids, tile: int = 512,
+                  interpret: bool | None = None):
+    """x: [N, d]; centroids: [NC, d] -> (assign [N] i32, sqdist [N] f32).
+    interpret=None resolves backend-aware (compiled on TPU, interpret
+    elsewhere)."""
+    if interpret is None:
+        from repro.kernels.ops import default_interpret
+        interpret = default_interpret()
     N, d = x.shape
     NC = centroids.shape[0]
     pad = (-N) % tile

@@ -1,6 +1,8 @@
 """Jit'd dispatch wrappers: Pallas kernel on TPU (or interpret=True on CPU
 for validation), pure-jnp reference otherwise. `use_pallas` is the build
-switch; interpret mode is selected automatically off-TPU.
+switch; every kernel resolves interpret mode from the platform through
+`default_interpret`, so nothing here catches a kernel failure and
+silently answers some other way.
 """
 from __future__ import annotations
 
@@ -20,71 +22,18 @@ from repro.kernels.decode_attention import (
 from repro.kernels.flash_prefill import flash_prefill as _flash_prefill
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def default_interpret() -> bool:
     """Backend-aware interpret default shared by every kernel dispatch:
     compiled Mosaic on real TPU, interpret mode (correctness-grade, runs
     the kernel body through XLA) everywhere else. Kernel entry points
     take `interpret=None` and resolve it here, so callers never hardcode
     a backend assumption."""
-    return not _on_tpu()
+    return jax.default_backend() != "tpu"
 
 
-# Mosaic support for lax.sort_key_val inside kernel bodies varies by
-# version; if the sort-based merge fails to lower on real TPU we fall back
-# to the argmin merge and remember (interpret mode always sorts). A racy
-# write from concurrent serving threads is benign: worst case both compile.
-_SORT_MERGE_BROKEN = False
-_SORT_MERGE_FAILS = 0
-# a genuine lowering failure sticks immediately; anything else (possibly
-# transient, e.g. RESOURCE_EXHAUSTED) gets this many sort retries before
-# we stop paying a doomed trace+compile on every call
-_SORT_MERGE_MAX_RETRIES = 3
-
-# deliberately narrow: the failing op is sort_key_val, so loose substrings
-# like "sort" would match transient errors too and defeat the retry budget
-_LOWERING_MARKERS = ("mosaic", "unimplemented", "not implemented",
-                     "unsupported", "cannot lower", "failed to lower")
-
-
-def _with_merge_fallback(call, merge, interpret):
-    global _SORT_MERGE_BROKEN, _SORT_MERGE_FAILS
-    if merge == "sort" and not interpret and _SORT_MERGE_BROKEN:
-        merge = "argmin"
-    try:
-        out = call(merge)
-        if merge == "sort" and not interpret:
-            _SORT_MERGE_FAILS = 0       # budget counts CONSECUTIVE failures
-        return out
-    except Exception as e:
-        if merge == "sort" and not interpret:
-            out = call("argmin")         # re-raises if merge wasn't the issue
-            _SORT_MERGE_FAILS += 1
-            is_lowering = any(m in str(e).lower() for m in _LOWERING_MARKERS)
-            if is_lowering or _SORT_MERGE_FAILS >= _SORT_MERGE_MAX_RETRIES:
-                import warnings
-                warnings.warn(
-                    f"ecoscan sort merge failed on "
-                    f"{jax.default_backend()} ({type(e).__name__}"
-                    f"{'' if is_lowering else ', persistent'}); using "
-                    f"the argmin merge from now on", stacklevel=3)
-                _SORT_MERGE_BROKEN = True
-            return out
-        raise
-
-
-def ecoscan(q, data, lens, probe_ids, k=10, use_pallas=True, merge="sort",
-            block_map=None):
+def ecoscan(q, data, lens, probe_ids, k=10, use_pallas=True, block_map=None):
     if use_pallas:
-        interpret = not _on_tpu()
-        return _with_merge_fallback(
-            lambda m: _ecoscan(q, data, lens, probe_ids, k=k,
-                               interpret=interpret, merge=m,
-                               block_map=block_map),
-            merge, interpret)
+        return _ecoscan(q, data, lens, probe_ids, k=k, block_map=block_map)
     return ref.ecoscan(q, data, lens, probe_ids, k, block_map=block_map)
 
 
@@ -97,28 +46,24 @@ def route_topk(q, centroids, n_probe=4, use_pallas=True):
 
 
 def route_and_scan(q, centroids, data, lens, n_probe=4, k=10,
-                   use_pallas=True, merge="sort"):
+                   use_pallas=True):
     """One fused device call: centroid routing + probed-cluster scan.
     Returns (dists [B,k], slots [B,k], probes [B,n_probe])."""
     if use_pallas:
-        interpret = not _on_tpu()
-        return _with_merge_fallback(
-            lambda m: _route_and_scan(q, centroids, data, lens,
-                                      n_probe=n_probe, k=k,
-                                      interpret=interpret, merge=m),
-            merge, interpret)
+        return _route_and_scan(q, centroids, data, lens, n_probe=n_probe,
+                               k=k)
     return ref.route_and_scan(q, centroids, data, lens, n_probe, k)
 
 
 def kmeans_assign(x, centroids, use_pallas=True):
     if use_pallas:
-        return _kmeans_assign(x, centroids, interpret=not _on_tpu())
+        return _kmeans_assign(x, centroids)
     return ref.kmeans_assign(x, centroids)
 
 
 def scr_score(windows, q, use_pallas=True):
     if use_pallas:
-        return _scr_score(windows, q, interpret=default_interpret())
+        return _scr_score(windows, q)
     return ref.scr_score(windows, q)
 
 
@@ -126,14 +71,13 @@ def scr_select(q, data, lens, doc_ids, use_pallas=True):
     """Fused SCR select: per-(query, retrieved doc) best window id and
     query·window score in one device call (DESIGN.md §7)."""
     if use_pallas:
-        return _scr_select(q, data, lens, doc_ids,
-                           interpret=default_interpret())
+        return _scr_select(q, data, lens, doc_ids)
     return ref.scr_select(q, data, lens, doc_ids)
 
 
 def pq_adc(lut, codes, use_pallas=True):
     if use_pallas:
-        return _pq_adc(lut, codes, interpret=default_interpret())
+        return _pq_adc(lut, codes)
     return ref.pq_adc(lut, codes)
 
 
@@ -142,8 +86,7 @@ def decode_attention(q, k, v, kv_len, use_pallas=True, ring=False):
     `ring=True` for per-slot sliding-window ring pages (mask length
     min(kv_len, S) per row)."""
     if use_pallas:
-        return _decode_attn(q, k, v, kv_len, interpret=default_interpret(),
-                            ring=ring)
+        return _decode_attn(q, k, v, kv_len, ring=ring)
     return ref.decode_attention(q, k, v, kv_len, ring=ring)
 
 
@@ -153,13 +96,11 @@ def decode_attention_paged(q, k, v, kv_len, table, use_pallas=True):
     grid step DMAs exactly one mapped page). `kv_len` [B] masks unmapped
     tail entries; ring callers pre-clamp it to the ring modulus."""
     if use_pallas:
-        return _decode_attn_paged(q, k, v, kv_len, table,
-                                  interpret=default_interpret())
+        return _decode_attn_paged(q, k, v, kv_len, table)
     return ref.decode_attention_paged(q, k, v, kv_len, table)
 
 
 def flash_prefill(q, k, v, causal=True, window=None, use_pallas=True):
     if use_pallas:
-        return _flash_prefill(q, k, v, causal=causal, window=window,
-                              interpret=not _on_tpu())
+        return _flash_prefill(q, k, v, causal=causal, window=window)
     return ref.flash_prefill(q, k, v, causal=causal, window=window)

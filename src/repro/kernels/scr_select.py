@@ -14,7 +14,9 @@ the device.
 Grid: (B, T) — T doc *tiles* per query (DOC_TILE document blocks DMA'd
 and reduced per step). Each step owns its private (1, DOC_TILE) slice of
 the output, so there are no revisited output blocks and no cross-step
-merge: the segment boundaries are exactly the document blocks.
+merge: the segment boundaries are exactly the document blocks. The
+per-document argmax is a masked min over window ids, so the first-max tie
+rule is explicit rather than left to the lowering.
 
 Doc ids < 0 are padding (queries that retrieved fewer than K docs):
 their block index is clamped to 0 and every window masked, yielding the
@@ -37,16 +39,19 @@ DEFAULT_DOC_TILE = 8
 
 def _kernel(ids_ref, lens_ref, q_ref, *refs, capw: int, dt: int):
     data_refs = refs[:dt]                           # dt x [1, CAPW, d]
-    out_s_ref, out_w_ref = refs[dt], refs[dt + 1]
+    out_s_ref, out_w_ref = refs[dt], refs[dt + 1]   # [1, dt] each
     b = pl.program_id(0)
     t = pl.program_id(1)
     q = q_ref[...]                                  # [1, d]
-    best_s, best_w = [], []
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, dt), 1)
+    out_s = jnp.full((1, dt), -NEG, jnp.float32)
+    out_w = jnp.full((1, dt), -1, jnp.int32)
     for j in range(dt):
         did = ids_ref[b, t * dt + j]
         safe = jnp.maximum(did, 0)                  # padded doc -> block 0
         w = data_refs[j][0]                         # [CAPW, d]
         s = jax.lax.dot_general(w, q, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32)
         s = s.T                                     # [1, CAPW]
         slot = jax.lax.broadcasted_iota(jnp.int32, (1, capw), 1)
@@ -54,14 +59,14 @@ def _kernel(ids_ref, lens_ref, q_ref, *refs, capw: int, dt: int):
         s = jnp.where(valid, s, -NEG)
         # segment-argmax within the document block: first max wins ties,
         # matching the host scan (Python max / jnp.argmax semantics)
-        best_s.append(jnp.max(s, axis=1, keepdims=True))          # [1, 1]
-        win = jnp.argmax(s, axis=1).astype(jnp.int32)[:, None]    # [1, 1]
-        has = jnp.any(valid)
-        best_w.append(jnp.where(has, win, -1))
-    out_s_ref[...] = (best_s[0] if dt == 1
-                      else jnp.concatenate(best_s, axis=1))       # [1, dt]
-    out_w_ref[...] = (best_w[0] if dt == 1
-                      else jnp.concatenate(best_w, axis=1))
+        best = jnp.max(s, axis=1, keepdims=True)                  # [1, 1]
+        win = jnp.min(jnp.where(s == best, slot, capw), axis=1,
+                      keepdims=True)                              # [1, 1]
+        has = jnp.max(valid.astype(jnp.int32), axis=1, keepdims=True) > 0
+        out_s = jnp.where(col == j, best, out_s)
+        out_w = jnp.where(col == j, jnp.where(has, win, -1), out_w)
+    out_s_ref[...] = out_s
+    out_w_ref[...] = out_w
 
 
 def _data_index(b, t, ids, ln, *, j, dt):
@@ -98,28 +103,32 @@ def scr_select(q, data, lens, doc_ids, interpret: bool | None = None,
         doc_ids = jnp.pad(doc_ids, ((0, 0), (0, T * dt - K)),
                           constant_values=-1)
 
+    # q rides as [B, 1, d] and the outputs as [B, T, 1, dt], leading dims
+    # squeezed out of the blocks, so every block's last two dims equal
+    # the array's at any B and T (Mosaic's block-shape rule)
+    out_row = pl.BlockSpec((pl.Squeezed(), pl.Squeezed(), 1, dt),
+                           lambda b, t, ids, ln: (b, t, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                      # doc_ids, lens
         grid=(B, T),
         in_specs=[
-            pl.BlockSpec((1, d), lambda b, t, ids, ln: (b, 0)),
+            pl.BlockSpec((pl.Squeezed(), 1, d),
+                         lambda b, t, ids, ln: (b, 0, 0)),
             *[pl.BlockSpec((1, CAPW, d),
                            functools.partial(_data_index, j=j, dt=dt))
               for j in range(dt)],
         ],
-        out_specs=[
-            pl.BlockSpec((1, dt), lambda b, t, ids, ln: (b, t)),
-            pl.BlockSpec((1, dt), lambda b, t, ids, ln: (b, t)),
-        ],
+        out_specs=[out_row, out_row],
     )
     kern = pl.pallas_call(
         functools.partial(_kernel, capw=CAPW, dt=dt),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, T * dt), jnp.float32),
-                   jax.ShapeDtypeStruct((B, T * dt), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((B, T, 1, dt), jnp.float32),
+                   jax.ShapeDtypeStruct((B, T, 1, dt), jnp.int32)],
         interpret=interpret,
     )
     data = data.astype(jnp.float32)
     out_s, out_w = kern(doc_ids, lens.astype(jnp.int32),
-                        q.astype(jnp.float32), *([data] * dt))
-    return out_s[:, :K], out_w[:, :K]
+                        q.astype(jnp.float32)[:, None, :], *([data] * dt))
+    return (out_s.reshape(B, T * dt)[:, :K],
+            out_w.reshape(B, T * dt)[:, :K])

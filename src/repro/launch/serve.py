@@ -6,6 +6,9 @@
   # streaming: Poisson arrivals into a live session, per-request latency
   PYTHONPATH=src python -m repro.launch.serve --stream --arrival-qps 4
 
+  # the paper's generator at its published widths (a chip's work)
+  PYTHONPATH=src python -m repro.launch.serve --stream --full
+
   # multi-replica: SlotScheduler over N continuous engines
   PYTHONPATH=src python -m repro.launch.serve --replicas 2
 
@@ -28,7 +31,9 @@ import time
 
 import numpy as np
 
+from repro.configs import get_config, get_reduced
 from repro.data.synthetic import make_qa_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.embedder import HashEmbedder
 from repro.serving.rag import PIPELINES, accuracy
 
@@ -176,6 +181,10 @@ def main():
                     choices=list(PIPELINES.keys()))
     ap.add_argument("--questions", type=int, default=8)
     ap.add_argument("--docs", type=int, default=150)
+    ap.add_argument("--full", action="store_true",
+                    help="generate with qwen25_0_5b at its published "
+                         "widths (random weights) instead of the reduced "
+                         "CPU smoke size")
     ap.add_argument("--replicas", type=int, default=1)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
@@ -219,14 +228,16 @@ def main():
                          "hot/cold EcoVector and forces device retrieval "
                          "so the tiers are exercised")
     args = ap.parse_args()
+    enable_compile_cache()
 
     corpus = make_qa_corpus("squad", n_docs=args.docs,
                             n_questions=args.questions, seed=args.seed)
     emb = HashEmbedder(dim=128)
-    pipe_kw = {}
+    gen = get_config if args.full else get_reduced
+    pipe_kw = {"gen_cfg": gen("qwen25_0_5b")}
     if args.device_budget is not None:
-        pipe_kw = {"device_budget_bytes": args.device_budget,
-                   "device_retrieval": True}
+        pipe_kw.update(device_budget_bytes=args.device_budget,
+                       device_retrieval=True)
     pipe = PIPELINES[args.pipeline](corpus.docs, emb, top_k=3, **pipe_kw)
     if hasattr(pipe, "_ensure_slm"):
         # the Engine is built lazily on first use, so the pool page

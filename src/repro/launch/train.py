@@ -104,6 +104,8 @@ def main():
     ap.add_argument("--kill-at", type=int, default=-1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     losses = run(args.arch, reduced=args.reduced, steps=args.steps,
                  batch=args.batch, seq=args.seq, ckpt_dir=args.ckpt_dir,
                  ckpt_interval=args.ckpt_interval, lr=args.lr,

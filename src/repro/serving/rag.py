@@ -15,7 +15,7 @@ import os
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional, Sequence
+from typing import TYPE_CHECKING, Callable, List, Optional, Sequence
 
 import numpy as np
 
@@ -25,6 +25,9 @@ from repro.core.ecovector import EcoVector
 from repro.core.scr import (SCRConfig, SCRResult, apply_scr, apply_scr_batch,
                             build_prompt)
 from repro.core.window_index import WindowIndex
+
+if TYPE_CHECKING:
+    from repro.config import ModelConfig
 
 # Table 6: measured on Galaxy S24
 SLM_SPEEDS = {
@@ -71,7 +74,7 @@ class RAGBase:
                  top_k: int = 3, slm: str = "qwen25_0_5b", index=None,
                  generator: Optional[Callable] = None,
                  device_retrieval: Optional[bool] = None,
-                 gen_arch: str = "qwen25_0_5b",
+                 gen_cfg: Optional["ModelConfig"] = None,
                  device_budget_bytes: Optional[float] = None,
                  _skip_corpus_embed: bool = False):
         self.docs = list(docs)
@@ -92,10 +95,11 @@ class RAGBase:
         # head) instead of raising — counted, never silent
         self.retrieval_fallbacks = 0
         self._last_good_ids: Optional[List[List[int]]] = None
-        # arch for answer(..., generate=True); the Table-6 `slm` keys are
-        # speed models only — real generation always runs a config that
-        # exists in repro.configs (reduced to CPU smoke size)
-        self.gen_arch = gen_arch
+        # generator config for answer(..., generate=True); the Table-6
+        # `slm` keys are speed models only. None is qwen25_0_5b at the
+        # reduced CPU smoke size; pass get_config("qwen25_0_5b") to
+        # generate at its published widths
+        self.gen_cfg = gen_cfg
         self._slm_engine = None
         if device_retrieval is not None:
             self.device_retrieval = device_retrieval
@@ -200,8 +204,10 @@ class RAGBase:
 
     def _ensure_slm(self):
         if self._slm_engine is None:
-            from repro.serving.slm import ReducedSLM
-            self._slm_engine = ReducedSLM(self.gen_arch)
+            from repro.configs import get_reduced
+            from repro.serving.slm import SLM
+            self._slm_engine = SLM(self.gen_cfg
+                                   or get_reduced("qwen25_0_5b"))
         return self._slm_engine
 
     def _attach_generation(self, answers: List[RAGAnswer],
@@ -262,7 +268,7 @@ class RAGBase:
                 deadline_s: Optional[float] = None,
                 trace=None, slo_s: Optional[float] = None):
         """A RagSession over this pipeline: submit/step/stream with
-        continuous-batching decode (raises ValueError when `gen_arch`
+        continuous-batching decode (raises ValueError when `gen_cfg`
         has no slot-paged KV path). `greedy=False` samples each request
         from its own co-residency-independent PRNG stream. `max_pending`
         bounds session admission (degrade past half, shed at the bound);

@@ -1,12 +1,14 @@
-"""On-device sLM: a reduced-config language model behind `serving.Engine`,
-with tokenisation, so RAG pipelines can run REAL generation on CPU.
+"""On-device sLM: a language model behind `serving.Engine`, with
+tokenisation, so RAG pipelines run REAL generation.
 
-The paper's phone-side models (Table 6) are stand-ins here: `qwen25_0_5b`
-reduced to the CPU smoke size with randomly initialised weights. The point
-is not answer quality — it is that the full on-device pipeline
-(EcoVector retrieval -> SCR -> prefill -> decode loop) executes end to
-end, with measured (not modelled) prefill/TTFT numbers next to the
-analytical Table-6 estimates.
+The generator's `ModelConfig` is passed in: `get_config("qwen25_0_5b")`
+is the paper's own generator at its published widths (what a chip
+serves), `get_reduced("qwen25_0_5b")` the CPU smoke size the tests use.
+Weights are random, made from `seed`. The point is not answer quality —
+it is that the full on-device pipeline (EcoVector retrieval -> SCR ->
+prefill -> decode loop) executes end to end, with measured (not
+modelled) prefill/TTFT numbers next to the analytical Table-6
+estimates.
 
 Prompts are left-truncated to the last `max_prompt` tokens and left-PADDED
 up to the next `pad_multiple` bucket: a handful of prefill shapes get
@@ -20,11 +22,14 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import List, Optional
+from typing import TYPE_CHECKING, List, Optional
 
 import numpy as np
 
 from repro.data.tokenizer import HashTokenizer
+
+if TYPE_CHECKING:
+    from repro.config import ModelConfig
 
 
 @dataclass
@@ -36,15 +41,16 @@ class SLMGeneration:
     decode_s: float = 0.0
 
 
-class ReducedSLM:
-    """Lazy Engine wrapper: the model stack is imported and initialised on
-    first use, so merely constructing pipelines (or importing rag.py) stays
-    free of the jax model chain."""
+class SLM:
+    """Lazy Engine wrapper over the generator config `cfg`: the model
+    stack is imported and initialised on first use, so merely
+    constructing pipelines (or importing rag.py) stays free of the jax
+    model chain."""
 
-    def __init__(self, arch: str = "qwen25_0_5b", *, max_prompt: int = 256,
+    def __init__(self, cfg: "ModelConfig", *, max_prompt: int = 256,
                  max_new: int = 24, pad_multiple: int = 32, seed: int = 0,
                  page_size: int = 32):
-        self.arch = arch
+        self.cfg = cfg
         self.max_prompt = max_prompt
         self.max_new = max_new
         self.pad_multiple = pad_multiple
@@ -56,10 +62,9 @@ class ReducedSLM:
     def _ensure(self):
         if self._engine is None:
             import jax
-            from repro.configs import get_reduced
             from repro.models import model
             from repro.serving.engine import Engine
-            cfg = get_reduced(self.arch)
+            cfg = self.cfg
             params = model.init_params(cfg, jax.random.PRNGKey(self.seed))
             self._engine = Engine(cfg, params,
                                   max_len=self.max_prompt + self.max_new,
@@ -106,7 +111,7 @@ class ReducedSLM:
             raise ValueError(
                 f"max_new={max_new} outside [1, {self.max_new}]: the "
                 "Engine KV budget is sized at construction — build "
-                "ReducedSLM(max_new=...) larger instead")
+                "SLM(max_new=...) larger instead")
         arrs = [self.encode_prompt(p) for p in prompts]
         if warm_first:
             # one throwaway pass over the same wave shapes so ttft_s

@@ -13,7 +13,8 @@ SCRIPT_SHARDED_RETRIEVAL = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.core.distributed import (make_sharded_retrieval,
                                         reference_retrieval)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_test_mesh
+    mesh = make_test_mesh(4, 2)
     rng = np.random.default_rng(0)
     NC, CAP, d, B, k, P = 16, 32, 24, 4, 5, 6
     data = rng.normal(size=(NC, CAP, d)).astype(np.float32)
@@ -38,6 +39,7 @@ SCRIPT_TRAIN_PARITY = textwrap.dedent("""
     from repro.config import RunConfig, ShapeConfig, TrainConfig
     from repro.configs import get_reduced
     from repro.dist.sharding import use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.models import model
     from repro.train import trainer
     cfg = get_reduced("h2o_danube_1_8b")
@@ -52,7 +54,7 @@ SCRIPT_TRAIN_PARITY = textwrap.dedent("""
     s1, _, _ = trainer.make_train_step(run, microbatches=1, seq_sp=False)
     p_ref, _, m_ref = s1(params, opt_state, batch)
     # sharded result on a 4x2 mesh
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_test_mesh(4, 2)
     with use_mesh(mesh):
         s2, _, _ = trainer.make_train_step(run, microbatches=1)
         psh, osh, bsh = trainer.state_shardings(run, mesh)
@@ -74,6 +76,7 @@ SCRIPT_MOE_PARITY = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_reduced
     from repro.dist.sharding import use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.models import model
     cfg = get_reduced("granite_moe_1b_a400m")
     params = model.init_params(cfg, jax.random.PRNGKey(0))
@@ -81,7 +84,7 @@ SCRIPT_MOE_PARITY = textwrap.dedent("""
     batch = {"tokens": jnp.asarray(rng.integers(4, 100, (4, 32)), jnp.int32),
              "labels": jnp.asarray(rng.integers(4, 100, (4, 32)), jnp.int32)}
     l1, _ = model.loss_fn(cfg, params, batch)   # local (no mesh) MoE path
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_test_mesh(2, 2)
     with use_mesh(mesh):
         l2, _ = jax.jit(lambda p, b: model.loss_fn(cfg, p, b))(params, batch)
     # shard_map EP with capacity drop may differ slightly from local path

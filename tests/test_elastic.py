@@ -17,6 +17,7 @@ SCRIPT = textwrap.dedent("""
     from repro.dist import checkpoint as ckpt
     from repro.dist.fault import reshard_restore
     from repro.dist.sharding import use_mesh, spec_tree_to_shardings
+    from repro.launch.mesh import make_test_mesh
     from repro.models import model
     from repro.train import trainer, optimizer as opt
 
@@ -29,7 +30,7 @@ SCRIPT = textwrap.dedent("""
              "labels": jnp.asarray(rng.integers(4, 100, (8, 32)), jnp.int32)}
 
     # ---- phase 1: train 2 steps on a 4x2 mesh, checkpoint
-    mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh1 = make_test_mesh(4, 2)
     with use_mesh(mesh1):
         params, opt_state = trainer.make_states(run, key=jax.random.PRNGKey(0))
         step, _, _ = trainer.make_train_step(run, microbatches=1)
@@ -42,8 +43,7 @@ SCRIPT = textwrap.dedent("""
         ref_loss = float(m1["loss"])
 
     # ---- phase 2: "lose half the cluster": restore onto a 2x2 mesh
-    mesh2 = jax.make_mesh((2, 2), ("data", "model"),
-                          devices=jax.devices()[:4])
+    mesh2 = make_test_mesh(2, 2, devices=jax.devices()[:4])
     with use_mesh(mesh2):
         like = trainer.make_states(run, abstract=True)
         pspecs = model.param_specs(cfg)
