@@ -1,0 +1,8 @@
+"""The whole serving step's share of the chip's bf16 peak
+(observe.step_mfu), beside the rooflines that move `itl_p95_ms`
+(decode_hbm_roofline): it bounds what any one of them can add."""
+from rag_bench import observe
+
+
+def read(obs):
+    return observe.step_mfu(obs)
