@@ -227,58 +227,6 @@ def run_prefix(mode="quick", seed=0):
         f"p50@90%={p50s[0.9]:.4f}s >= p50@0%={p50s[0.0]:.4f}s")
 
 
-def run_trace_overhead(mode="quick", seed=0):
-    """Gate: span tracing must cost < 5% on p50 request latency.
-
-    Alternates traced and untraced drains of the same ragged workload on
-    one engine (interleaved so clock/thermal drift cancels), measures
-    each drain with wall timers — identical instrumentation in both arms
-    — and compares the median of per-arm p50s."""
-    import jax
-    from repro.configs import get_reduced
-    from repro.models import model
-    from repro.serving.engine import ContinuousEngine
-    from repro.serving.trace import TraceSink
-
-    prompts, gens = _workload(mode, seed=seed)
-    n = 8 if mode == "quick" else len(prompts)
-    prompts, gens = prompts[:n], gens[:n]
-    cfg = get_reduced("qwen25_0_5b")
-    params = model.init_params(cfg, jax.random.PRNGKey(0))
-    ce = ContinuousEngine(cfg, params, slots=SLOTS, max_len=MAX_LEN)
-    ce.warmup()
-
-    def drain():
-        t0 = time.perf_counter()
-        sub = {ce.submit(p, int(g)): time.perf_counter() - t0
-               for p, g in zip(prompts, gens)}
-        lat = {}
-        while ce.pending:
-            for ev in ce.step():
-                if ev.kind == "done":
-                    lat[ev.rid] = (time.perf_counter() - t0
-                                   - sub[ev.rid])
-        return float(np.percentile(list(lat.values()), 50))
-
-    drain()                               # shape warm-up, untimed
-    reps = 3 if mode == "quick" else 5
-    p50s = {True: [], False: []}
-    for _ in range(reps):
-        for traced in (True, False):
-            ce.trace = TraceSink() if traced else None
-            p50s[traced].append(drain())
-    ce.trace = None
-    on = float(np.median(p50s[True]))
-    off = float(np.median(p50s[False]))
-    overhead = (on - off) / off
-    emit("serving.trace_overhead", overhead * 1e6,
-         f"p50_on_ms={on * 1e3:.1f};p50_off_ms={off * 1e3:.1f};"
-         f"reps={reps};n={n}")
-    assert overhead < 0.05, (
-        f"tracing overhead {overhead:.1%} >= 5% p50 "
-        f"(on={on * 1e3:.1f}ms off={off * 1e3:.1f}ms)")
-
-
 def run_chaos(mode="quick", seed=0, trace_export=None):
     """Goodput under a seeded FaultPlan: every request either completes
     within its deadline or is explicitly shed — the emitted row asserts
@@ -400,8 +348,6 @@ if __name__ == "__main__":
                     help="goodput-under-chaos section only")
     ap.add_argument("--prefix", action="store_true",
                     help="shared-prefix TTFT sweep only")
-    ap.add_argument("--trace-overhead", action="store_true",
-                    help="tracing-overhead gate (< 5%% p50) only")
     ap.add_argument("--trace-export", default=None, metavar="PATH",
                     help="with --chaos: export the run's TraceSink as "
                          "JSONL for tools/trace_check.py")
@@ -411,7 +357,5 @@ if __name__ == "__main__":
         run_chaos(a.mode, a.seed, trace_export=a.trace_export)
     elif a.prefix:
         run_prefix(a.mode, a.seed)
-    elif a.trace_overhead:
-        run_trace_overhead(a.mode, a.seed)
     else:
         run(a.mode)

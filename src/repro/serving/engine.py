@@ -48,7 +48,7 @@ import numpy as np
 from repro.config import ModelConfig
 from repro.models import model
 from repro.serving.pager import PagePool, PoolStats, PrefixCache
-from repro.serving.trace import TraceSink
+from repro.serving.trace import NO_SPAN, TraceSink
 
 # monotone engine-instance counter: the `src` tag on trace records, so
 # replicas sharing one TraceSink never collide on request ids
@@ -59,7 +59,9 @@ _ENGINE_SEQ = [0]
 class GenResult:
     """One finished generation: decoded token ids (including the EOS, if
     hit), the prompt length, and measured prefill / decode wall time
-    attributed to this request."""
+    attributed to this request. On the paged path `prefill_s` runs from
+    the request's admission to its first token (the chunks of other
+    requests and the decode steps between its own chunks included)."""
     tokens: List[int]
     prompt_len: int
     prefill_s: float = 0.0
@@ -101,7 +103,8 @@ class _Request:
     matched: int = 0                 # prefix tokens reused from the cache
     slot: int = -1
     pages: List[int] = field(default_factory=list)
-    prefill_s: float = 0.0
+    admitted_s: float = 0.0          # on the trace sink's clock
+    prefill_s: float = 0.0           # admission -> first token
     decode_s: float = 0.0
     greedy: bool = True
     # sampled requests only: this request's own PRNG stream root,
@@ -273,11 +276,25 @@ class ContinuousEngine:
     # ------------------------------------------------------------ tracing
 
     def _emit(self, name: str, rid: int = -1, *, comp: str = "engine",
-              ph: str = "I", **attrs) -> None:
-        """One trace record from this engine (no-op without a sink)."""
+              **attrs):
+        """One trace record from this engine; None without a sink."""
         if self.trace is not None:
-            self.trace.emit(comp, name, rid, src=self.trace_src, ph=ph,
-                            **attrs)
+            return self.trace.emit(comp, name, rid, src=self.trace_src,
+                                   **attrs)
+        return None
+
+    def _span(self, name: str, rid: int = -1, **attrs):
+        """An `engine/<name>` span (serving/trace.py); a shared no-op
+        context without a sink."""
+        if self.trace is None:
+            return NO_SPAN
+        return self.trace.span("engine", name, rid, src=self.trace_src,
+                               **attrs)
+
+    def _now(self, rec) -> float:
+        """A record's timestamp, or the sink's clock source when there
+        is no sink (and so no record)."""
+        return rec.ts if rec is not None else time.perf_counter()
 
     def _trace_page_stats(self) -> None:
         """Snapshot pool accounting into the trace: tools/trace_check.py
@@ -295,14 +312,17 @@ class ContinuousEngine:
 
     def submit(self, prompt: np.ndarray, max_new: int = 32,
                rid: Optional[int] = None, *, greedy: bool = True,
-               seed: int = 0) -> int:
+               seed: int = 0, parent_src: Optional[str] = None,
+               parent_rid: Optional[int] = None) -> int:
         """Queue one request; returns its rid. A prompt whose pages
         (prompt + max_new tokens) exceed the slot table width is shed
         with a terminal "shed" event at admission — never silently
         truncated. `greedy=False` samples from this request's own PRNG
         stream `fold_in(PRNGKey(seed), rid)` — pass an explicit `rid` to
         make a sampled request's draws reproducible across engines/runs
-        regardless of what else is co-resident."""
+        regardless of what else is co-resident. `parent_src` /
+        `parent_rid` name the caller's request that caused this one (a
+        session request); the `queued` record carries them."""
         if rid is None:
             rid = self._next_rid
         self._next_rid = max(self._next_rid, rid) + 1
@@ -313,8 +333,10 @@ class ContinuousEngine:
             req.key = jax.random.fold_in(jax.random.PRNGKey(seed), rid)
         self.queue.append(req)
         self._inflight[rid] = req
+        link = ({} if parent_src is None
+                else {"parent_src": parent_src, "parent_rid": parent_rid})
         self._emit("queued", rid, prompt_len=len(p), max_new=max_new,
-                   greedy=greedy)
+                   greedy=greedy, **link)
         return rid
 
     def _draw(self, req: _Request, row: np.ndarray) -> int:
@@ -410,8 +432,11 @@ class ContinuousEngine:
         """Record one emitted token; finish the request on EOS/max_new."""
         req.tokens.append(tok)
         events.append(EngineEvent(req.rid, "token", token=tok))
-        self._emit("first_token" if len(req.tokens) == 1 else "token",
-                   req.rid, token=tok)
+        if len(req.tokens) == 1:
+            rec = self._emit("first_token", req.rid, token=tok)
+            req.prefill_s = self._now(rec) - req.admitted_s
+        else:
+            self._emit("token", req.rid, token=tok)
         if tok == self.eos_id or len(req.tokens) >= req.max_new:
             self._finish(req, events)
 
@@ -460,7 +485,6 @@ class ContinuousEngine:
             if any(r is not None for r in self._occupant):
                 return "wait"
             return "shed"
-        t0 = time.perf_counter()
         if cow:
             # fork the partially matching page: one page copy, then the
             # resumed prefill overwrites everything past the match point
@@ -471,7 +495,6 @@ class ContinuousEngine:
                        dst_page=fresh[0], copy_len=cow[1])
         req.pages = full + fresh
         req.matched = req.filled = matched
-        req.prefill_s += time.perf_counter() - t0
         self._tbl[s, :len(req.pages)] = req.pages
         self._tbl[s, len(req.pages):] = 0
         self._tbl_dev = None
@@ -484,16 +507,28 @@ class ContinuousEngine:
 
     def _admit(self, events: List[EngineEvent]) -> None:
         """Assign queued requests to free slots (prefill starts on the
-        same step, via `_prefill_step`). Oversize requests shed loudly;
-        a transient page shortage leaves the queue intact until live
-        slots free their pages."""
+        same step, via `_prefill_step`), inside an `engine/admit` span
+        whose `admitted` attr counts them (no span when nothing is
+        queued). Oversize requests shed loudly; a transient page
+        shortage leaves the queue intact until live slots free their
+        pages."""
+        if not self.queue:
+            return
+        with self._span("admit") as b:
+            n = self._admit_queued(events)
+            if b is not None:
+                b.attrs["admitted"] = n
+
+    def _admit_queued(self, events: List[EngineEvent]) -> int:
+        """`_admit`'s body; returns how many requests it admitted."""
+        n = 0
         for s in range(self.slots):
             while self._occupant[s] is None and self.queue:
                 req = self.queue.popleft()
                 st = self._map_request(req, s)
                 if st == "wait":
                     self.queue.appendleft(req)
-                    return
+                    return n
                 if st == "shed":
                     self._inflight.pop(req.rid, None)
                     self.shed += 1
@@ -507,8 +542,11 @@ class ContinuousEngine:
                 self._occupant[s] = req
                 self.active[s] = False
                 events.append(EngineEvent(req.rid, "admitted"))
-                self._emit("admitted", req.rid, slot=s,
-                           matched=req.matched, pages=len(req.pages))
+                rec = self._emit("admitted", req.rid, slot=s,
+                                 matched=req.matched, pages=len(req.pages))
+                req.admitted_s = self._now(rec)
+                n += 1
+        return n
 
     def _prefill_step(self, events: List[EngineEvent]) -> None:
         """Advance every admitting slot by one prompt chunk. A request
@@ -522,61 +560,78 @@ class ContinuousEngine:
             req = self._occupant[s]
             if req is None or self.active[s]:
                 continue
-            t0 = time.perf_counter()
             end = min(len(req.prompt), (req.filled // c + 1) * c)
             chunk = req.prompt[req.filled:end]
             real = len(chunk)
             if real < c:
                 chunk = np.concatenate([chunk, np.zeros(c - real, np.int32)])
-            self._emit("prefill_chunk", req.rid, ph="B", slot=s,
-                       start=req.filled, n=real)
-            logits, self.cache = self._chunk(
-                self.params, self.cache, jnp.asarray(chunk[None]),
-                jnp.asarray(self._tbl[s]), jnp.int32(req.filled),
-                jnp.int32(req.filled + real))
-            req.filled += real
-            self._emit("prefill_chunk", req.rid, ph="E")
+            with self._span("prefill_chunk", req.rid, slot=s,
+                            start=req.filled, n=real):
+                logits, self.cache = self._chunk(
+                    self.params, self.cache, jnp.asarray(chunk[None]),
+                    jnp.asarray(self._tbl[s]), jnp.int32(req.filled),
+                    jnp.int32(req.filled + real))
+                req.filled += real
             if req.filled >= len(req.prompt):
                 plen = len(req.prompt)
                 if self.prefix is not None:
                     self.prefix.register(req.prompt,
                                          req.pages[:-(-plen // self.page_size)])
-                row = np.asarray(logits, np.float32)[0, real - 1]
-                tok = self._draw(req, row)
+                with self._span("prefill_readback", req.rid):
+                    row = np.asarray(logits, np.float32)[0, real - 1]
+                    tok = self._draw(req, row)
                 self.pos[s] = plen
                 self.last_tok[s] = tok
                 self.active[s] = True
-                req.prefill_s += time.perf_counter() - t0
                 self._emit_token(req, tok, events)
-            else:
-                req.prefill_s += time.perf_counter() - t0
+
+    def _page_use(self) -> tuple:
+        """(pages reserved, pages holding K/V) over the decoding slots:
+        the pages mapped into their table rows, and ceil(pos / page_size)
+        of them (at most the row's pages: a ring wraps)."""
+        ps = self.page_size
+        reserved = live = 0
+        for s in np.flatnonzero(self.active):
+            n = len(self._occupant[s].pages)
+            reserved += n
+            live += min(n, -(-int(self.pos[s]) // ps))
+        return reserved, live
 
     def _decode_step(self, events: List[EngineEvent]) -> None:
         """One `decode_step_paged` over every active slot, then one
         batched `_sample_rows` draw (greedy argmax rows and per-request
         PRNG-stream rows in the same jitted call — only [slots] ints ever
-        reach the host)."""
+        reach the host). Traced as an `engine/decode_step` span carrying
+        the KV pages reserved and in use, with the wait for the drawn
+        tokens as an `engine/decode_readback` span inside it."""
         if not self.active.any():
             return
         t0 = time.perf_counter()
-        self._emit("decode_step", ph="B", active=int(self.active.sum()))
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(self.last_tok[:, None]),
-            jnp.asarray(self.pos), jnp.asarray(self.active),
-            self._table_dev())
-        keys = np.zeros((self.slots, 2), np.uint32)
-        ts = np.zeros(self.slots, np.int32)
-        gr = np.ones(self.slots, bool)
-        for s in range(self.slots):
-            req = self._occupant[s]
-            if self.active[s] and not req.greedy:
-                keys[s] = np.asarray(req.key)
-                ts[s] = len(req.tokens)
-                gr[s] = False
-        nxt = np.asarray(_sample_rows(logits, jnp.asarray(keys),
-                                      jnp.asarray(ts), jnp.asarray(gr)))
-        dt = time.perf_counter() - t0
-        self._emit("decode_step", ph="E")
+        if self.trace is None:
+            step = NO_SPAN
+        else:
+            reserved, live = self._page_use()
+            step = self._span("decode_step", active=int(self.active.sum()),
+                              pages_reserved=reserved, pages_live=live)
+        with step:
+            logits, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(self.last_tok[:, None]),
+                jnp.asarray(self.pos), jnp.asarray(self.active),
+                self._table_dev())
+            keys = np.zeros((self.slots, 2), np.uint32)
+            ts = np.zeros(self.slots, np.int32)
+            gr = np.ones(self.slots, bool)
+            for s in range(self.slots):
+                req = self._occupant[s]
+                if self.active[s] and not req.greedy:
+                    keys[s] = np.asarray(req.key)
+                    ts[s] = len(req.tokens)
+                    gr[s] = False
+            with self._span("decode_readback"):
+                nxt = np.asarray(_sample_rows(logits, jnp.asarray(keys),
+                                              jnp.asarray(ts),
+                                              jnp.asarray(gr)))
+            dt = time.perf_counter() - t0
         self.steps += 1
         self.active_slot_steps += int(self.active.sum())
         for s in range(self.slots):

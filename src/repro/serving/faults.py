@@ -193,7 +193,10 @@ class ChaosPipeline:
         self.errors = plan.retrieval_errors()
         self.calls = 0
         self.injected = 0
-        self.trace = trace            # optional shared TraceSink
+        # optional shared TraceSink for "chaos injected" records; kept
+        # off the `trace` name, which reads through to the wrapped
+        # pipeline (the one a RagSession hands its sink to)
+        self._sink = trace
 
     def __getattr__(self, name):
         return getattr(self.inner, name)
@@ -203,8 +206,8 @@ class ChaosPipeline:
         self.calls += 1
         if idx in self.errors:
             self.injected += 1
-            if self.trace is not None:
-                self.trace.emit("chaos", "injected",
+            if self._sink is not None:
+                self._sink.emit("chaos", "injected",
                                 kind="retrieval_error", call=idx)
             raise InjectedFault(f"retrieval error @ call {idx}")
         return self.inner.answer_batch(queries, **kw)

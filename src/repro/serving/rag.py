@@ -25,6 +25,7 @@ from repro.core.ecovector import EcoVector
 from repro.core.scr import (SCRConfig, SCRResult, apply_scr, apply_scr_batch,
                             build_prompt)
 from repro.core.window_index import WindowIndex
+from repro.serving.trace import NO_SPAN
 
 if TYPE_CHECKING:
     from repro.config import ModelConfig
@@ -101,6 +102,9 @@ class RAGBase:
         # generate at its published widths
         self.gen_cfg = gen_cfg
         self._slm_engine = None
+        # the TraceSink of the RagSession serving this pipeline, which
+        # records its stages as comp="rag" spans (None = untraced)
+        self.trace = None
         if device_retrieval is not None:
             self.device_retrieval = device_retrieval
         if hasattr(embed, "fit") and not getattr(embed, "fitted", True):
@@ -116,6 +120,12 @@ class RAGBase:
             self.index.set_device_budget(
                 self._resolve_device_budget(self.index))
         self.build_s = time.perf_counter() - t0
+
+    def _span(self, name: str, **attrs):
+        """A `rag/<name>` span; a shared no-op context untraced."""
+        if self.trace is None:
+            return NO_SPAN
+        return self.trace.span("rag", name, **attrs)
 
     def _resolve_device_budget(self, index) -> int:
         b = self.device_budget_bytes
@@ -511,7 +521,10 @@ class MobileRAG(RAGBase):
                      max_new: int = 16) -> List[RAGAnswer]:
         """Fully batched MobileRAG: ONE query embed feeds both the fused
         EcoVector retrieval and the fused SCR select; everything after the
-        two device calls is host-side string assembly. `generate=True`
+        two device calls is host-side string assembly. Traced, the stages
+        are `rag/embed`, `rag/search` (retrieval and its readback),
+        `rag/scr` and `rag/prompt` spans, each with the batch size `n`.
+        `generate=True`
         routes through the RagSession (whose retrieval chunks re-enter
         this fused path with generate=False) so SCR for the next chunk
         overlaps continuous decode of the previous one."""
@@ -522,30 +535,35 @@ class MobileRAG(RAGBase):
         if generate:
             return self._answer_batch_generate(queries, max_new)
         self._sync_window_index()
+        n = len(queries)
         t0 = time.perf_counter()
-        qvs = np.asarray(self.embed(queries), np.float32)
-        ids_b = self._retrieve_batch(qvs, self.top_k)
-        t_ret = (time.perf_counter() - t0) / len(queries)
+        with self._span("embed", n=n):
+            qvs = np.asarray(self.embed(queries), np.float32)
+        with self._span("search", n=n):
+            ids_b = self._retrieve_batch(qvs, self.top_k)
+        t_ret = (time.perf_counter() - t0) / n
         t1 = time.perf_counter()
         try:
-            results = apply_scr_batch(queries, ids_b, self.window_index,
-                                      self.embed, qvs=qvs)
+            with self._span("scr", n=n):
+                results = apply_scr_batch(queries, ids_b, self.window_index,
+                                          self.embed, qvs=qvs)
         except Exception:
             # SCR stage down for the whole batch: degrade every query to
             # its full retrieved docs instead of raising
             self.scr_fallbacks += 1
-            t_post = (time.perf_counter() - t1) / len(queries)
+            t_post = (time.perf_counter() - t1) / n
             return [self._finalize(
                         q, self._make_prompt(q, [self.docs[i] for i in ids],
                                              ids), ids, t_ret, t_post)
                     for q, ids in zip(queries, ids_b)]
-        t_post = (time.perf_counter() - t1) / len(queries)
+        t_post = (time.perf_counter() - t1) / n
         out = []
-        for q, ids, res in zip(queries, ids_b, results):
-            prompt = build_prompt(q, res)
-            out.append(self._finalize(q, prompt,
-                                      [ids[i] for i in res.order],
-                                      t_ret, t_post, scr=res))
+        with self._span("prompt", n=n):
+            for q, ids, res in zip(queries, ids_b, results):
+                prompt = build_prompt(q, res)
+                out.append(self._finalize(q, prompt,
+                                          [ids[i] for i in res.order],
+                                          t_ret, t_post, scr=res))
         return out
 
 

@@ -34,7 +34,7 @@ from typing import Deque, Dict, Iterable, Iterator, List, Optional
 from collections import deque
 
 from repro.serving.engine import ContinuousEngine
-from repro.serving.trace import SLOController, TraceSink
+from repro.serving.trace import NO_SPAN, SLOController, TraceSink
 
 # request lifecycle states; "done" / "shed" / "failed" are terminal
 STATES = ("submitted", "retrieved", "condensed", "decoding",
@@ -110,11 +110,11 @@ class RagSession:
         `max_pending` bounds admission: past HALF the bound the session
         degrades (halved retrieve_chunk and max_new); at the bound new
         submissions are shed. `deadline_s` is the default per-request
-        deadline. `trace` attaches a shared TraceSink to the session AND
-        its engine (comp="session"/"engine"); `slo_s` is the default SLO
-        budget per request — with a sink attached, each request is planned
-        through `SLOController` (degrade before shed) against the tighter
-        of its deadline and its SLO budget. Raises ValueError when the
+        deadline. `trace` attaches a shared TraceSink to the session, its
+        engine and its pipeline (comp="session"/"engine"/"rag"); `slo_s`
+        is the default SLO budget per request — with a sink attached,
+        each request is planned through `SLOController` (degrade before
+        shed) against the tighter of its deadline and its SLO budget. Raises ValueError when the
         pipeline's generation arch has no slot-paged KV path
         (`model.supports_paged`)."""
         self.pipe = pipe
@@ -136,6 +136,7 @@ class RagSession:
         self.engine: ContinuousEngine = slm.continuous(slots)  # may raise
         if trace is not None:
             self.engine.trace = trace
+            self._pipe_owner("trace").trace = trace
         self._slm = slm
         self._n_probe0 = getattr(pipe, "n_probe", 4)
         self.requests: Dict[int, RagRequest] = {}
@@ -152,6 +153,13 @@ class RagSession:
         if self.trace is not None:
             self.trace.emit("session", name, rid, src=self.trace_src,
                             **attrs)
+
+    def _span(self, name: str, rid: int = -1, **attrs):
+        """A `session/<name>` span; a shared no-op context untraced."""
+        if self.trace is None:
+            return NO_SPAN
+        return self.trace.span("session", name, rid, src=self.trace_src,
+                               **attrs)
 
     # ------------------------------------------------------------- intake
 
@@ -260,16 +268,20 @@ class RagSession:
             cands.append(req.submitted_s + self.slo_s - now)
         return min(cands) if cands else None
 
-    def _set_n_probe(self, n: int) -> None:
-        """Set the retrieval probe count on the real pipeline: chaos (and
-        other) wrappers delegate reads via __getattr__ but a plain setattr
-        would land on the wrapper, so walk the `.inner` chain down to the
+    def _pipe_owner(self, attr: str):
+        """The pipeline object that owns `attr`: chaos (and other)
+        wrappers delegate reads via __getattr__ but a plain setattr would
+        land on the wrapper, so walk the `.inner` chain down to the
         object that actually owns the attribute."""
         pipe = self.pipe
-        while "n_probe" not in vars(pipe) and \
+        while attr not in vars(pipe) and \
                 getattr(pipe, "inner", None) is not None:
             pipe = pipe.inner
-        pipe.n_probe = n
+        return pipe
+
+    def _set_n_probe(self, n: int) -> None:
+        """Set the retrieval probe count on the real pipeline."""
+        self._pipe_owner("n_probe").n_probe = n
 
     def _plan_step(self, chunk: int, events: List[RagEvent]) -> tuple:
         """SLO-plan the head of the queue before retrieval: degrade
@@ -320,12 +332,8 @@ class RagSession:
         if n_probe != self._n_probe0:
             self._set_n_probe(n_probe)
         try:
-            if self.trace is not None:
-                with self.trace.span("session", "retrieve",
-                                     src=self.trace_src, n=len(reqs),
-                                     n_probe=n_probe):
-                    answers = self._condense(reqs)
-            else:
+            with self._span("retrieve", n=len(reqs), n_probe=n_probe,
+                            rids=take):
                 answers = self._condense(reqs)
         finally:
             if n_probe != self._n_probe0:
@@ -347,9 +355,12 @@ class RagSession:
             self._emit("retrieved", req.req_id, docs=len(ans.doc_ids))
             self._emit("condensed", req.req_id,
                        prompt_tokens=ans.prompt_tokens)
-            prompt = self._slm.encode_prompt(ans.prompt, bucket=False)
-            erid = self.engine.submit(prompt, req.max_new,
-                                      greedy=self.greedy, seed=self.seed)
+            with self._span("encode", req.req_id):
+                prompt = self._slm.encode_prompt(ans.prompt, bucket=False)
+                erid = self.engine.submit(prompt, req.max_new,
+                                          greedy=self.greedy, seed=self.seed,
+                                          parent_src=self.trace_src,
+                                          parent_rid=req.req_id)
             self._decoding[erid] = req
             req.state = "decoding"
 
@@ -390,13 +401,16 @@ class RagSession:
 
     def step(self) -> List[RagEvent]:
         """Advance the session: flush submit-time events, shed expired
-        requests, one retrieval/condense chunk, one engine step. Returns
-        the events produced (possibly empty when idle)."""
+        requests, one retrieval/condense chunk, one engine step, all in
+        one `session/step` span. Returns the events produced (possibly
+        empty when idle)."""
         events: List[RagEvent] = self._events_out
         self._events_out = []
-        self._expire_step(events)
-        self._retrieve_step(events)
-        self._engine_step(events)
+        with self._span("step", queued=len(self._queued),
+                        decoding=len(self._decoding)):
+            self._expire_step(events)
+            self._retrieve_step(events)
+            self._engine_step(events)
         return events
 
     # ----------------------------------------------------------- draining

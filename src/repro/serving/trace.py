@@ -19,17 +19,25 @@ perf_counter timestamp (clamped so the record stream is ordered even if
 the clock hiccups), `src` the emitting component instance (engine
 replicas share one sink without rid collisions), `rid` the request id in
 the component's namespace (-1 for component-level records), and `ph` the
-phase: "I" instant, or "B"/"E" bracketing a span (prefill_chunk,
-decode_step, retrieve). In OTel terms: comp+src is the instrumentation
-scope, rid the trace id, name the span name, B/E the span boundaries.
+phase: "I" instant, or "B"/"E" bracketing a span. In OTel terms:
+comp+src is the instrumentation scope, rid the trace id, name the span
+name, B/E the span boundaries.
+
+Spans are opened only through `TraceSink.span`. The serving loop is one
+thread, so spans nest: each B record's `parent` attr is the seq of the
+innermost span still open (-1 at the top), which makes the records a
+tree, and each span is mirrored onto the profiler's clock as a
+`jax.profiler.TraceAnnotation` named `comp/name` that carries the B
+record's `seq` — a device trace of the same window joins back to these
+records by that number. Without a sink nothing is recorded or annotated.
 
 `TraceSink` is a bounded ring buffer (oldest records evicted, counted in
 `evicted`) that is exportable to JSONL (`export_jsonl`) and queryable
 in-process (`query`, `durations`, `percentile`). Recording is pure
-host-side bookkeeping — a deque append — so tracing NEVER touches device
-state: tokens are bit-identical with a sink attached or not
-(tests/test_paged_families.py, tests/test_pager.py), and the overhead
-gate in `bench_serving --trace-overhead` keeps it under 5% p50.
+host-side bookkeeping — a deque append and a profiler annotation — so
+tracing NEVER touches device state: tokens are bit-identical with a sink
+attached or not (tests/test_paged_families.py, tests/test_pager.py).
+Its cost on the chip is measured in PERF.md.
 
 `SLOController` turns the live trace window into admission decisions:
 it estimates a request's end-to-end cost from observed p95 stage costs
@@ -49,14 +57,20 @@ from __future__ import annotations
 import json
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
-from typing import Callable, Dict, Iterator, List, Optional
+from typing import Callable, Dict, List, Optional
+
+import jax
 
 # Request lifecycle taxonomy. Terminal names are shared by every comp;
 # which non-terminal names a comp may emit (and their order) is encoded
 # in tools/trace_check.py's per-comp rules.
 TERMINALS = ("done", "shed", "failed", "cancelled")
+
+# what a component's span helper returns when no sink is attached: one
+# shared no-op context, so an untraced span constructs nothing
+NO_SPAN = nullcontext()
 
 
 @dataclass
@@ -95,6 +109,7 @@ class TraceSink:
         self._seq = 0
         self._last_ts = 0.0
         self.evicted = 0
+        self._open: List[int] = []        # B seqs of the open spans
 
     def __len__(self) -> int:
         return len(self._buf)
@@ -121,11 +136,19 @@ class TraceSink:
     def span(self, comp: str, name: str, rid: int = -1, *, src: str = "",
              **attrs):
         """Bracket a stage with B/E records (one span = one B + one E
-        with the same (comp, src, name, rid) key)."""
-        self.emit(comp, name, rid, src=src, ph="B", **attrs)
+        with the same (comp, src, name, rid) key) and yield the B record.
+        The B record's `parent` attr is the seq of the innermost open
+        span (-1 at the top); the span also runs inside a
+        `jax.profiler.TraceAnnotation("comp/name", seq=<B seq>)`, which
+        lands on the profiler's host plane when a profile is running."""
+        b = self.emit(comp, name, rid, src=src, ph="B",
+                      parent=self._open[-1] if self._open else -1, **attrs)
+        self._open.append(b.seq)
         try:
-            yield
+            with jax.profiler.TraceAnnotation(f"{comp}/{name}", seq=b.seq):
+                yield b
         finally:
+            self._open.pop()
             self.emit(comp, name, rid, src=src, ph="E")
 
     # ------------------------------------------------------------- query
@@ -221,12 +244,14 @@ class SLOController:
         decode             = max_new * p95(engine.decode_step)
 
     (one decode step emits one token per active slot, so the per-token
-    cost IS the step cost). A missing term (cold window) disables the
-    estimate and the plan is "admit" — the controller never sheds on no
-    evidence. The ladder, in order: clamp max_new to what fits the
-    budget after retrieval+prefill; shrink this step's retrieve_chunk;
-    halve n_probe (floor `min_probe`). If the floor configuration
-    (1 token, chunk 1, min probes) still exceeds the budget: "shed"."""
+    cost IS the step cost; a prefill_chunk span brackets the chunk's
+    dispatch, not its device time, so the prefill term is priced low).
+    A missing term (cold window) disables the estimate and the plan is
+    "admit" — the controller never sheds on no evidence. The ladder, in
+    order: clamp max_new to what fits the budget after retrieval+prefill;
+    shrink this step's retrieve_chunk; halve n_probe (floor `min_probe`).
+    If the floor configuration (1 token, chunk 1, min probes) still
+    exceeds the budget: "shed"."""
 
     def __init__(self, sink: TraceSink, *, window: int = 128,
                  min_tokens: int = 1, min_chunk: int = 1,

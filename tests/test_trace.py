@@ -1,8 +1,13 @@
-"""Per-request span tracing + SLO admission (PR-10 acceptance).
+"""Per-request span tracing + SLO admission.
 
 Covers the trace layer as a correctness ORACLE, not just logging:
   - TraceSink semantics: monotone timestamps under clock skew, ring
-    eviction accounting, span pairing, JSONL export/load round trip;
+    eviction accounting, span pairing, JSONL export/load round trip,
+    the span tree (`parent` links) and its mirror onto the profiler's
+    clock (`TraceAnnotation` carrying each span's `seq`);
+  - a traced session's stage spans: their tree, the session -> engine
+    request link, KV pages reserved against pages in use, the prefill
+    interval; and an untraced session recording and annotating nothing;
   - tools/trace_check.py catches every class of lifecycle violation it
     claims to (order, orphans, double terminals, unclosed spans, page
     leaks, silent fault drops) and passes real engine/session runs —
@@ -12,6 +17,7 @@ Covers the trace layer as a correctness ORACLE, not just logging:
   - SLOController: degrade-before-shed ladder from live p95 stage
     costs, never shedding blind, wired through RagSession admission.
 """
+import contextlib
 import importlib.util
 import pathlib
 
@@ -88,6 +94,53 @@ def test_jsonl_roundtrip(tmp_path):
     assert back[0].attrs["prompt_len"] == 8
 
 
+class _Annotations:
+    """Stands in for `jax.profiler.TraceAnnotation`: records each
+    annotation's name and metadata."""
+
+    def __init__(self):
+        self.seen = []
+
+    def __call__(self, name, **meta):
+        self.seen.append((name, meta))
+        return contextlib.nullcontext()
+
+
+def test_sink_span_tree_and_annotation_mirror(monkeypatch):
+    """Nested spans name the innermost open span as `parent` (-1 at the
+    top), close children first, and each enters one profiler annotation
+    named comp/name with the B record's seq."""
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    sink = TraceSink()
+    with sink.span("session", "step", src="s0") as step:
+        with sink.span("session", "retrieve", src="s0", n=1) as ret:
+            with sink.span("rag", "embed", n=1) as emb:
+                pass
+        with sink.span("engine", "decode_step", src="e0") as dec:
+            pass
+    with sink.span("session", "step", src="s0") as top:
+        pass
+    assert [b.attrs["parent"] for b in (step, ret, emb, dec, top)] \
+        == [-1, step.seq, ret.seq, step.seq, -1]
+    assert ann.seen == [(f"{b.comp}/{b.name}", {"seq": b.seq})
+                        for b in (step, ret, emb, dec, top)]
+    recs = sink.records()
+    assert [(r.name, r.ph) for r in recs] == [
+        ("step", "B"), ("retrieve", "B"), ("embed", "B"), ("embed", "E"),
+        ("retrieve", "E"), ("decode_step", "B"), ("decode_step", "E"),
+        ("step", "E"), ("step", "B"), ("step", "E")]
+    assert not trace_check.check_records(recs)
+    # an exception inside a span still closes it, and the stack unwinds
+    with pytest.raises(ValueError):
+        with sink.span("session", "step", src="s0"):
+            raise ValueError
+    with sink.span("session", "step", src="s0") as after:
+        pass
+    assert after.attrs["parent"] == -1
+    assert not trace_check.check_records(sink.records())
+
+
 # ------------------------------------------------- checker catches badness
 
 
@@ -139,6 +192,65 @@ def test_checker_accepts_good_chain_and_recycled_rid():
 ])
 def test_checker_flags_lifecycle_violations(mutate, needle):
     viol = trace_check.check_records(mutate(_good_chain()))
+    assert viol and any(needle in v for v in viol), viol
+
+
+def _tree(*rows):
+    """Records from (name, ph, parent) rows; a session request 0 queued
+    and condensed first, so session-scoped spans may follow."""
+    head = [_r(0, "session", "queued", 0, src="s0"),
+            _r(1, "session", "retrieved", 0, src="s0"),
+            _r(2, "session", "condensed", 0, src="s0")]
+    out = list(head)
+    for i, (comp, name, ph, rid, attrs) in enumerate(rows, len(head)):
+        out.append(_r(i, comp, name, rid, ph=ph, src="s0", **attrs))
+    return out
+
+
+def test_checker_accepts_a_span_tree():
+    recs = _tree(("session", "step", "B", -1, {"parent": -1}),
+                 ("session", "encode", "B", 0, {"parent": 3}),
+                 ("engine", "queued", "I", 0,
+                  {"parent_src": "s0", "parent_rid": 0}),
+                 ("session", "encode", "E", 0, {}),
+                 ("session", "step", "E", -1, {}),
+                 ("engine", "admitted", "I", 0, {}),
+                 ("engine", "prefill_readback", "B", 0, {"parent": -1}),
+                 ("engine", "prefill_readback", "E", 0, {}),
+                 ("engine", "first_token", "I", 0, {}),
+                 ("engine", "done", "I", 0, {}),
+                 ("session", "done", "I", 0, {}))
+    assert trace_check.check_records(recs) == []
+
+
+@pytest.mark.parametrize("rows, needle", [
+    # a span name the program does not open
+    ((("engine", "warp", "B", -1, {}), ("engine", "warp", "E", -1, {})),
+     "unknown span"),
+    # the last chunk's readback of a request never admitted
+    ((("engine", "queued", "I", 0, {}),
+      ("engine", "prefill_readback", "B", 0, {}),
+      ("engine", "prefill_readback", "E", 0, {})), "before any of"),
+    # encoding a session request that was never condensed
+    ((("session", "queued", "I", 1, {}),
+      ("session", "encode", "B", 1, {}),
+      ("session", "encode", "E", 1, {})), "before any of"),
+    # a parent other than the innermost open span
+    ((("session", "step", "B", -1, {"parent": -1}),
+      ("session", "retrieve", "B", -1, {"parent": -1}),
+      ("session", "retrieve", "E", -1, {}),
+      ("session", "step", "E", -1, {})), "not the innermost"),
+    # a parent that closes while its child is open
+    ((("session", "step", "B", -1, {"parent": -1}),
+      ("session", "retrieve", "B", -1, {"parent": 3}),
+      ("session", "step", "E", -1, {}),
+      ("session", "retrieve", "E", -1, {})), "opened inside it"),
+    # an engine request caused by a session request never queued
+    ((("engine", "queued", "I", 0,
+       {"parent_src": "s0", "parent_rid": 7}),), "never queued"),
+])
+def test_checker_flags_span_tree_violations(rows, needle):
+    viol = trace_check.check_records(_tree(*rows), complete=False)
     assert viol and any(needle in v for v in viol), viol
 
 
@@ -461,3 +573,128 @@ def test_session_slo_degrade_reduces_n_probe(corpus, monkeypatch):
     assert seen[-1] == 2                  # degraded probe width applied
     assert pipe.n_probe == 4              # and restored after the chunk
     assert sess.counters.degraded_slo == 1
+
+
+@pytest.fixture(scope="module")
+def traced_session(corpus):
+    """One traced session run of four queries, two a retrieval chunk,
+    over two slots, with the profiler annotations recorded."""
+    ann = _Annotations()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax.profiler, "TraceAnnotation", ann)
+        pipe = _mobile(corpus)
+        sink = TraceSink()
+        sess = pipe.session(max_new=4, slots=2, retrieve_chunk=2,
+                            trace=sink)
+        sess.run([e.question for e in corpus.examples[:4]])
+    return sess, sink.records(), ann.seen
+
+
+def test_session_span_tree(traced_session):
+    """Every stage span of a session run nests as the table in
+    docs/OBSERVABILITY.md says: each B's parent is open when it begins,
+    and every span closes before the span it opened in."""
+    sess, recs, _ = traced_session
+    assert trace_check.check_records(recs) == []
+    by_seq = {r.seq: r for r in recs}
+    # the engine's warm-up at session construction runs untouched by the
+    # session: its spans are top-level
+    first_step = min(r.seq for r in recs if r.name == "step")
+    stack, parent_name = [], {}
+    for r in recs:
+        if r.ph == "B":
+            p = r.attrs["parent"]
+            assert p == (stack[-1].seq if stack else -1)
+            if r.seq < first_step:
+                assert r.comp == "engine"
+            else:
+                parent_name.setdefault(f"{r.comp}/{r.name}", set()).add(
+                    "-" if p < 0
+                    else f"{by_seq[p].comp}/{by_seq[p].name}")
+            stack.append(r)
+        elif r.ph == "E":
+            top = stack.pop()
+            assert (top.comp, top.src, top.rid, top.name) \
+                == (r.comp, r.src, r.rid, r.name)
+    assert not stack
+    assert parent_name == {
+        "session/step": {"-"},
+        "session/retrieve": {"session/step"},
+        "rag/embed": {"session/retrieve"},
+        "rag/search": {"session/retrieve"},
+        "rag/scr": {"session/retrieve"},
+        "rag/prompt": {"session/retrieve"},
+        "session/encode": {"session/step"},
+        "engine/admit": {"session/step"},
+        "engine/prefill_chunk": {"session/step"},
+        "engine/prefill_readback": {"session/step"},
+        "engine/decode_step": {"session/step"},
+        "engine/decode_readback": {"engine/decode_step"},
+    }
+
+
+def test_session_engine_link(traced_session):
+    """Each engine request names the session request that caused it,
+    and that request rode in the `rids` of a retrieve span before it."""
+    sess, recs, _ = traced_session
+    first_step = min(r.seq for r in recs if r.name == "step")
+    recs = [r for r in recs if r.seq >= first_step]   # past warm-up
+    retrieved = []
+    links = {}
+    for r in recs:
+        if r.comp == "session" and r.name == "retrieve" and r.ph == "B":
+            assert len(r.attrs["rids"]) == r.attrs["n"]
+            retrieved += r.attrs["rids"]
+        if r.comp == "engine" and r.name == "queued":
+            assert r.attrs["parent_src"] == sess.trace_src
+            assert r.attrs["parent_rid"] in retrieved
+            links[r.rid] = r.attrs["parent_rid"]
+    assert sorted(retrieved) == [0, 1, 2, 3]
+    assert sorted(links.values()) == [0, 1, 2, 3]
+    admits = [r for r in recs if r.name == "admit" and r.ph == "B"]
+    assert sum(b.attrs["admitted"] for b in admits) == 4
+
+
+def test_decode_steps_count_pages(traced_session):
+    """Decode steps carry the KV pages mapped to decoding slots and the
+    pages holding their K/V: never more in use than reserved."""
+    _, recs, _ = traced_session
+    steps = [r.attrs for r in recs
+             if r.name == "decode_step" and r.ph == "B"]
+    assert steps
+    assert all(0 < a["pages_live"] <= a["pages_reserved"] for a in steps)
+
+
+def test_spans_mirrored_as_annotations(traced_session):
+    """One profiler annotation per span, named comp/name, carrying the
+    seq of the span's B record."""
+    _, recs, seen = traced_session
+    assert seen == [(f"{r.comp}/{r.name}", {"seq": r.seq})
+                    for r in recs if r.ph == "B"]
+
+
+def test_prefill_s_is_admission_to_first_token(traced_session):
+    """A request's prefill_s is its first_token record's time less its
+    admitted record's: the interval `prefill_ms_per_request` reads."""
+    _, recs, _ = traced_session
+    at = {}
+    for r in recs:
+        if r.comp == "engine" and r.name in ("admitted", "first_token"):
+            at[(r.rid, r.name)] = r.ts
+        if r.comp == "engine" and r.name == "done":
+            assert r.attrs["prefill_s"] == (at[(r.rid, "first_token")]
+                                            - at[(r.rid, "admitted")])
+            assert r.attrs["prefill_s"] > 0
+
+
+def test_untraced_session_records_and_annotates_nothing(corpus,
+                                                        monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    pipe = _mobile(corpus)
+    sess = pipe.session(max_new=4, slots=2, retrieve_chunk=2)
+    out = sess.run([e.question for e in corpus.examples[:2]])
+    assert all(a is not None and a.ttft_measured_s > 0 for a in out)
+    assert sess.trace is None and sess.engine.trace is None
+    assert pipe.trace is None
+    assert ann.seen == []

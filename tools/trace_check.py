@@ -8,14 +8,20 @@ in docs/OBSERVABILITY.md:
   ordering    seq strictly increasing, ts monotone non-decreasing;
   lifecycle   per (comp, src, rid) the event DAG is respected —
               engine:  queued -> admitted -> prefill_chunk* ->
-                       first_token -> token* -> done | shed | cancelled
-              session: queued -> retrieved -> condensed ->
+                       prefill_readback -> first_token -> token* ->
+                       done | shed | cancelled
+              session: queued -> retrieved -> condensed -> encode ->
                        done | shed | failed
               sched:   queued -> placed/requeue/hedge* -> done | shed
               with nothing after a terminal and at most one terminal;
   spans       every B has a matching E on the same (comp, src, rid)
-              key, never nested, none left open at end of a complete
-              trace (prefill_chunk, decode_step, retrieve);
+              key, never re-opened, none left open at end of a complete
+              trace, and only the known span names (SPAN_NAMES);
+  tree        spans nest: a B's `parent` attr is the seq of the
+              innermost span still open (-1 at the top), and no span
+              closes while a span opened inside it is still open;
+  links       an engine `queued` with `parent_src`/`parent_rid` names a
+              session request queued before it;
   terminals   in a complete trace every request that entered a
               component reaches exactly one terminal state there —
               chaos may delay requests, never strand them;
@@ -53,19 +59,24 @@ from typing import Dict, Iterable, List, Optional, Tuple
 TERMINALS = {"engine": {"done", "shed", "cancelled"},
              "session": {"done", "shed", "failed"},
              "sched": {"done", "shed"}}
-SPAN_NAMES = {("engine", "prefill_chunk"), ("engine", "decode_step"),
-              ("session", "retrieve")}
+SPAN_NAMES = {("engine", "admit"), ("engine", "prefill_chunk"),
+              ("engine", "prefill_readback"), ("engine", "decode_step"),
+              ("engine", "decode_readback"), ("session", "step"),
+              ("session", "retrieve"), ("session", "encode"),
+              ("rag", "embed"), ("rag", "search"), ("rag", "scr"),
+              ("rag", "prompt")}
 # per-comp event -> prerequisites (any one suffices); "" = may be first
 PREREQS = {
     "engine": {"queued": set(), "admitted": {"queued"},
                "prefill_chunk": {"admitted"},
+               "prefill_readback": {"admitted"},
                "first_token": {"admitted"}, "token": {"first_token"},
                "done": {"first_token"}, "shed": {"queued"},
                "cancelled": {"queued"}},
     "session": {"queued": set(), "degraded": {"queued"},
                 "retrieved": {"queued"}, "condensed": {"retrieved"},
-                "done": {"condensed"}, "failed": {"queued"},
-                "shed": {"queued"}},
+                "encode": {"condensed"}, "done": {"condensed"},
+                "failed": {"queued"}, "shed": {"queued"}},
     "sched": {"queued": set(), "degraded": {"queued"},
               "placed": {"queued"}, "requeue": {"placed"},
               "hedge": {"placed"}, "done": {"placed"},
@@ -169,9 +180,13 @@ class TraceChecker:
     def _check_spans(self) -> None:
         open_b: Dict[Tuple, int] = {}
         for r in self.records:
-            if (r["comp"], r["name"]) not in SPAN_NAMES:
+            if r.get("ph") not in ("B", "E"):
                 continue
             key = (r["comp"], r["src"], r["rid"], r["name"])
+            if (r["comp"], r["name"]) not in SPAN_NAMES:
+                if r.get("ph") == "B":
+                    self._bad(r, f"{key}: unknown span")
+                continue
             if r.get("ph") == "B":
                 if key in open_b:
                     self._bad(r, f"{key}: span re-opened (B at seq "
@@ -187,6 +202,49 @@ class TraceChecker:
             for key, seq in open_b.items():
                 self._bad(None, f"{key}: span opened at seq {seq} "
                                 f"never closed")
+
+    def _check_tree(self) -> None:
+        first = self.records[0]["seq"] if self.records else 0
+        stack: List[Tuple[Tuple, int]] = []      # open (key, B seq)
+        for r in self.records:
+            ph = r.get("ph")
+            if ph not in ("B", "E"):
+                continue
+            key = (r["comp"], r["src"], r["rid"], r["name"])
+            if ph == "B":
+                parent = r["attrs"].get("parent")
+                want = stack[-1][1] if stack else -1
+                # a parent opened before a ring truncation is not here
+                lost = self.truncated and not stack \
+                    and parent is not None and parent < first
+                if parent is not None and parent != want and not lost:
+                    self._bad(r, f"{key}: parent {parent} is not the "
+                                 f"innermost open span ({want})")
+                stack.append((key, r["seq"]))
+                continue
+            at = [i for i, (k, _) in enumerate(stack) if k == key]
+            if not at:
+                continue                  # the span check reports it
+            if at[-1] != len(stack) - 1:
+                self._bad(r, f"{key}: closes before {stack[-1][0]} "
+                             f"opened inside it")
+            del stack[at[-1]]
+
+    def _check_links(self) -> None:
+        queued: set = set()
+        for r in self.records:
+            if r["name"] != "queued":
+                continue
+            if r["comp"] == "session":
+                queued.add((r["src"], r["rid"]))
+            a = r["attrs"]
+            if r["comp"] == "engine" and "parent_src" in a \
+                    and (a["parent_src"], a["parent_rid"]) not in queued \
+                    and not self.truncated:
+                self._bad(r, f"engine request {(r['src'], r['rid'])} "
+                             f"links to session request "
+                             f"{(a['parent_src'], a['parent_rid'])}, "
+                             f"which was never queued")
 
     # ------------------------------------------------------------- pager
 
@@ -273,6 +331,8 @@ class TraceChecker:
         self._check_ordering()
         self._check_lifecycle()
         self._check_spans()
+        self._check_tree()
+        self._check_links()
         self._check_pager()
         self._check_chaos()
         self._check_replica()
