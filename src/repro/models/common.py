@@ -49,12 +49,17 @@ def shape_tree(defs, dtype):
 F32_KEEP = ("lam", "A_log", "dt_bias", "D")
 
 
+def leaf_name(path) -> str:
+    """The name of a param-tree leaf: the last key of its path."""
+    last = path[-1]
+    return getattr(last, "key", None) or str(last)
+
+
 def cast_params(tree, dtype):
     """Mixed-precision policy: cast weights to compute dtype at use-site
     (differentiable, so grads flow to the f32 masters)."""
     def f(path, a):
-        last = path[-1]
-        name = getattr(last, "key", None) or str(last)
+        name = leaf_name(path)
         if name in F32_KEEP:
             return a
         return a.astype(dtype) if a.dtype == jnp.float32 else a

@@ -2,6 +2,7 @@
 
   init_params / param_shapes / param_specs
   loss_fn                         (training objective, all families)
+  serving_params                  (the weights a serving engine holds)
   prefill / decode_step           (serving)
   make_batch_specs / make_cache   (ShapeDtypeStruct builders for dry-run)
 """
@@ -14,7 +15,8 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig, ShapeConfig
 from repro.models import dense, encdec, mamba2, moe, rglru
-from repro.models.common import cast_params, init_tree, shape_tree, spec_tree
+from repro.models.common import (cast_params, init_tree, leaf_name,
+                                 shape_tree, spec_tree)
 from repro.models.encdec import DEC_RATIO
 
 FAMILIES = {
@@ -101,6 +103,21 @@ def loss_fn(cfg: ModelConfig, params, batch, *, seq_sp: bool = False,
 def prefill(cfg: ModelConfig, params, batch):
     return family(cfg).prefill(cfg, cast_params(params, compute_dtype(cfg)),
                                batch)
+
+
+def serving_params(cfg: ModelConfig, params):
+    """The weights a serving engine holds: `params` cast once to the
+    compute dtype, so no jitted call re-casts float32 masters of the
+    whole model (serving never updates its weights). `F32_KEEP` leaves
+    stay float32, as `cast_params` leaves them, and so do the norm
+    scales (leaves named `*norm`, a few vectors a layer): the norms
+    compute in float32, and on a TPU v5e scales held in bfloat16 gave
+    logits a bfloat16 step away from those of the per-call cast, where
+    float32 ones give the same numbers bit for bit."""
+    held = cast_params(params, compute_dtype(cfg))
+    return jax.tree_util.tree_map_with_path(
+        lambda path, master, cast: (master if leaf_name(path).endswith("norm")
+                                    else cast), params, held)
 
 
 def decode_step(cfg: ModelConfig, params, cache, token, pos):

@@ -183,14 +183,16 @@ class ContinuousEngine:
         chunk-prefill executables. `oversize_pages` widens every table
         row beyond the ceil(max_len / page_size) baseline so a request
         slightly over budget can still be admitted from transiently free
-        pages instead of shed. Raises ValueError for configs without
+        pages instead of shed. `params` is held as
+        `model.serving_params` casts it, and float32 masters passed in
+        are not kept. Raises ValueError for configs without
         slot-paged support (`model.supports_paged`)."""
         if not model.supports_paged(cfg):
             raise ValueError(
                 f"{cfg.name}: family/config without slot-paged KV support "
                 "(use Engine's wave path)")
         self.cfg = cfg
-        self.params = params
+        self.params = model.serving_params(cfg, params)
         self.slots = slots
         self.max_len = max_len
         self.eos_id = eos_id
@@ -704,9 +706,10 @@ class Engine:
         `slots`: default concurrent-request count of the shared
         ContinuousEngine; `prefill_chunk`: tokens per admission prefill
         chunk; `page_size`: positions per KV pool page (both continuous
-        path only)."""
+        path only). `params` is held as `model.serving_params` casts
+        it, and shared with the continuous engines as it is."""
         self.cfg = cfg
-        self.params = params
+        self.params = model.serving_params(cfg, params)
         self.max_len = max_len
         self.eos_id = eos_id
         self.slots = slots

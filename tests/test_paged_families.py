@@ -211,3 +211,111 @@ def test_tracing_zero_interference_families(kind):
     assert len(sink.query(comp="engine", name="done")) == len(prompts)
     assert len(sink.query(comp="engine", name="first_token")) \
         == len(prompts)
+
+
+def _direct_greedy(cfg, params, prompt, max_new, *, slots, max_len,
+                   chunk=32, ps=32, oversize_pages=2, eos=2):
+    """Greedy tokens of one request driven straight through
+    `model.prefill_chunk_paged` / `model.decode_step_paged` on `params`
+    (float32 masters are cast inside every call), in slot 0 of `slots`,
+    with the chunking and page-table width a `ContinuousEngine` of the
+    same settings uses. Returns (tokens, the logits rows drawn from)."""
+    W = -(-max_len // ps) + oversize_pages
+    abs_len = -(-max_len // chunk) * chunk
+    cache = model.init_page_pool(cfg, slots * W, ps,
+                                 dtype=model.compute_dtype(cfg))
+    table = np.zeros((slots, W), np.int32)
+    table[0] = np.arange(W)
+    prefill = jax.jit(lambda p, c, t, row, off, lim: model.prefill_chunk_paged(
+        cfg, p, c, t, row, off, lim, page_size=ps, abs_len=abs_len))
+    decode = jax.jit(lambda p, c, t, pos, act, tbl: model.decode_step_paged(
+        cfg, p, c, t, pos, act, tbl, page_size=ps))
+    for off in range(0, len(prompt), chunk):
+        real = len(prompt[off:off + chunk])
+        toks = np.zeros((1, chunk), np.int32)
+        toks[0, :real] = prompt[off:off + chunk]
+        logits, cache = prefill(params, cache, toks, table[0],
+                                np.int32(off), np.int32(off + real))
+    rows = [np.asarray(logits, np.float32)[0, real - 1]]
+    out = [int(np.argmax(rows[-1]))]
+    pos = np.zeros(slots, np.int32)
+    pos[0] = len(prompt)
+    active = np.zeros(slots, bool)
+    active[0] = True
+    last = np.zeros((slots, 1), np.int32)
+    while len(out) < max_new and out[-1] != eos:
+        last[0, 0] = out[-1]
+        logits, cache = decode(params, cache, last, pos, active, table)
+        rows.append(np.asarray(logits, np.float32)[0])
+        out.append(int(np.argmax(rows[-1])))
+        pos[0] += 1
+    return out, np.stack(rows)
+
+
+@pytest.mark.parametrize("arch", ["qwen25_0_5b", "granite_moe_1b_a400m"])
+def test_engine_holds_compute_dtype_weights(arch):
+    """Serving casts the float32 masters once, where the engine takes
+    them: every leaf the engine holds is bfloat16 but the `F32_KEEP`
+    leaves and the norm scales, the continuous engines share those
+    arrays, neither jitted program takes any other float32 argument, and
+    no reference to a float32 matrix survives construction."""
+    import gc
+    import weakref
+
+    import jax.numpy as jnp
+    from repro.models.common import F32_KEEP, leaf_name
+
+    def kept(path):
+        name = leaf_name(path)
+        return name in F32_KEEP or name.endswith("norm")
+
+    cfg = get_reduced(arch)
+    assert model.compute_dtype(cfg) == jnp.bfloat16
+    masters = model.init_params(cfg, jax.random.PRNGKey(0))
+    assert all(x.dtype == jnp.float32 for x in jax.tree.leaves(masters))
+    eng = Engine(cfg, masters, max_len=64, slots=2)
+    ce = eng.continuous()
+    held = jax.tree_util.tree_leaves_with_path(ce.params)
+    assert any(kept(p) for p, _ in held)
+    for path, x in held:
+        assert x.dtype == (jnp.float32 if kept(path) else jnp.bfloat16), path
+    for a, b in zip(jax.tree.leaves(eng.params), jax.tree.leaves(ce.params)):
+        assert a is b
+
+    s, w = ce.slots, ce.table_width
+    decode = ce._decode.lower(
+        ce.params, ce.cache, np.zeros((s, 1), np.int32),
+        np.zeros(s, np.int32), np.zeros(s, bool), np.zeros((s, w), np.int32))
+    chunk = ce._chunk.lower(
+        ce.params, ce.cache, np.zeros((1, ce.prefill_chunk), np.int32),
+        np.zeros(w, np.int32), np.int32(0), np.int32(1))
+    for low in (decode, chunk):
+        f32 = [p for p, a in jax.tree_util.tree_leaves_with_path(
+            low.args_info) if a.dtype == jnp.float32]
+        assert f32 and all(kept(p) for p in f32), f32
+
+    refs = [weakref.ref(x) for p, x in
+            jax.tree_util.tree_leaves_with_path(masters) if not kept(p)]
+    del masters
+    gc.collect()
+    assert all(r() is None for r in refs)
+
+
+@pytest.mark.parametrize("arch", ["qwen25_0_5b", "granite_moe_1b_a400m"])
+def test_served_tokens_match_per_call_cast(arch):
+    """An engine built from float32 masters serves, bit for bit, the
+    greedy tokens of a loop that hands the same masters to
+    `model.prefill_chunk_paged` / `model.decode_step_paged`, which cast
+    them in every call: casting once changes no value the matmuls see,
+    so the same loop on the engine's weights gives the same logits bit
+    for bit."""
+    cfg = get_reduced(arch)
+    masters = model.init_params(cfg, jax.random.PRNGKey(4))
+    prompts = _prompts(seed=13, lens=(40, 9, 33))
+    ce = ContinuousEngine(cfg, masters, slots=2, max_len=96)
+    served = [r.tokens for r in ce.generate(prompts, max_new=7)]
+    for p, want in zip(prompts, served):
+        toks, rows = _direct_greedy(cfg, masters, p, 7, slots=2, max_len=96)
+        assert toks == want
+        _, held = _direct_greedy(cfg, ce.params, p, 7, slots=2, max_len=96)
+        np.testing.assert_array_equal(held, rows)
