@@ -18,6 +18,7 @@ expert id, cumsum offsets, capacity drop) — no [T, E, C] one-hot.
 from __future__ import annotations
 
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -92,6 +93,30 @@ q8_all_gather.defvjp(_q8_fwd, _q8_bwd)
 # ----------------------------------------------------------- dispatch core
 
 
+class ServingRows(NamedTuple):
+    """Expert-rows of one serving-FFN call in one layer."""
+    capacity: int     # buffer rows each expert computes
+    routed: int       # expert-rows the real tokens were routed to
+    computed: int     # expert-rows the experts compute
+
+
+def serving_rows(cfg: ModelConfig, n_rows: int,
+                 n_real: int | None = None) -> ServingRows:
+    """The serving FFN (`moe_ffn(drop=False)`) over `n_rows` buffer rows,
+    of which `n_real` are real tokens (default all). Capacity is
+    `n_rows`: top_k picks distinct experts per token, so an expert
+    receives at most one slot per row and none is ever dropped. Every
+    expert computes its whole capacity, routed or not (on a mesh each
+    shard computes its own experts' part); the real tokens were routed
+    to `n_real * top_k` of those rows. `_local_moe` serves with this
+    capacity and the engine traces these counts, and `computed` is
+    derived from `capacity`, so a change to the capacity moves both."""
+    real = n_rows if n_real is None else n_real
+    capacity = n_rows
+    return ServingRows(capacity, real * cfg.moe.top_k,
+                       cfg.moe.num_experts * capacity)
+
+
 def _local_moe(cfg: ModelConfig, x, router, we1, we2, we3, e_offset, E_total,
                capacity: int | None = None):
     """Token-choice top-k MoE over the experts resident in this shard.
@@ -101,10 +126,11 @@ def _local_moe(cfg: ModelConfig, x, router, we1, we2, we3, e_offset, E_total,
     experts, aux load-balance loss term).
 
     `capacity` overrides the capacity_factor-derived per-expert buffer
-    size. Serving paths pass T — the true no-drop bound, since top_k
-    assigns distinct experts per token: every token's output then
-    depends only on its own row, which is what makes wave/paged decode
-    bit-identical and a slot's tokens independent of its co-residents.
+    size. Serving paths pass T (`serving_rows`) — the true no-drop
+    bound, since top_k assigns distinct experts per token: every token's
+    output then depends only on its own row, which is what makes
+    wave/paged decode bit-identical and a slot's tokens independent of
+    its co-residents.
     Training keeps the capacity_factor drops.
     """
     m = cfg.moe
@@ -164,13 +190,19 @@ def moe_ffn(cfg: ModelConfig, lp, x, *, out_scatter: bool = False,
     DISTINCT experts per token, so one expert can receive at most one
     slot per token) — no token is ever dropped, and a co-batched (or
     junk co-resident) token can never displace another request's
-    expert slot.
+    expert slot. Op metadata names it `moe_ffn` (a named scope), so a
+    profiler trace can attribute its operations.
     """
+    with jax.named_scope("moe_ffn"):
+        return _moe_ffn(cfg, lp, x, out_scatter=out_scatter, drop=drop)
+
+
+def _moe_ffn(cfg: ModelConfig, lp, x, *, out_scatter: bool, drop: bool):
     b, s, d = x.shape
     mesh = get_mesh()
     m = cfg.moe
     if mesh is None or "model" not in mesh.axis_names:
-        cap = None if drop else b * s
+        cap = None if drop else serving_rows(cfg, b * s).capacity
         y, aux = _local_moe(cfg, x.reshape(-1, d), lp["router"], lp["we1"],
                             lp["we2"], lp.get("we3"), 0, m.num_experts,
                             capacity=cap)
@@ -198,7 +230,7 @@ def moe_ffn(cfg: ModelConfig, lp, x, *, out_scatter: bool = False,
         we3_f = gather(we3_l, 1, 2) if cfg.act == "swiglu" else None
         midx = jax.lax.axis_index("model")
         xt = xl.reshape(-1, d)
-        cap = None if drop else xt.shape[0]
+        cap = None if drop else serving_rows(cfg, xt.shape[0]).capacity
         y, aux = _local_moe(cfg, xt, router_f, we1_f, we2_f, we3_f,
                             midx * El, m.num_experts, capacity=cap)
         y = y.reshape(xl.shape)

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import ModelConfig
-from repro.models import model
+from repro.models import model, moe
 from repro.serving.pager import PagePool, PoolStats, PrefixCache
 from repro.serving.trace import NO_SPAN, TraceSink
 
@@ -292,6 +292,15 @@ class ContinuousEngine:
             return NO_SPAN
         return self.trace.span("engine", name, rid, src=self.trace_src,
                                **attrs)
+
+    def _moe_rows(self, n_rows: int, n_real: int) -> dict:
+        """Span attrs of a MoE engine's call over `n_rows` rows holding
+        `n_real` real tokens: the expert-rows routed and computed
+        (`moe.serving_rows`), summed over layers."""
+        r = moe.serving_rows(self.cfg, n_rows, n_real)
+        layers = self.cfg.num_layers
+        return {"moe_rows_routed": r.routed * layers,
+                "moe_rows_computed": r.computed * layers}
 
     def _now(self, rec) -> float:
         """A record's timestamp, or the sink's clock source when there
@@ -556,7 +565,8 @@ class ContinuousEngine:
         the next chunk boundary, so all later chunks land on the same
         grid a cold prefill uses — that alignment (plus identical shared
         page contents) is what keeps a prefix hit bit-identical to a
-        cold run."""
+        cold run. Each chunk is traced as an `engine/prefill_chunk` span
+        (for MoE carrying the expert-rows routed and computed)."""
         c = self.prefill_chunk
         for s in range(self.slots):
             req = self._occupant[s]
@@ -567,8 +577,10 @@ class ContinuousEngine:
             real = len(chunk)
             if real < c:
                 chunk = np.concatenate([chunk, np.zeros(c - real, np.int32)])
+            rows = (self._moe_rows(c, real) if self.trace is not None
+                    and self.cfg.family == "moe" else {})
             with self._span("prefill_chunk", req.rid, slot=s,
-                            start=req.filled, n=real):
+                            start=req.filled, n=real, **rows):
                 logits, self.cache = self._chunk(
                     self.params, self.cache, jnp.asarray(chunk[None]),
                     jnp.asarray(self._tbl[s]), jnp.int32(req.filled),
@@ -604,8 +616,9 @@ class ContinuousEngine:
         batched `_sample_rows` draw (greedy argmax rows and per-request
         PRNG-stream rows in the same jitted call — only [slots] ints ever
         reach the host). Traced as an `engine/decode_step` span carrying
-        the KV pages reserved and in use, with the wait for the drawn
-        tokens as an `engine/decode_readback` span inside it."""
+        the KV pages reserved and in use (and, for MoE, the expert-rows
+        routed and computed), with the wait for the drawn tokens as an
+        `engine/decode_readback` span inside it."""
         if not self.active.any():
             return
         t0 = time.perf_counter()
@@ -613,8 +626,12 @@ class ContinuousEngine:
             step = NO_SPAN
         else:
             reserved, live = self._page_use()
-            step = self._span("decode_step", active=int(self.active.sum()),
-                              pages_reserved=reserved, pages_live=live)
+            n = int(self.active.sum())
+            rows = (self._moe_rows(self.slots, n)
+                    if self.cfg.family == "moe" else {})
+            step = self._span("decode_step", active=n,
+                              pages_reserved=reserved, pages_live=live,
+                              **rows)
         with step:
             logits, self.cache = self._decode(
                 self.params, self.cache, jnp.asarray(self.last_tok[:, None]),

@@ -319,3 +319,87 @@ def test_served_tokens_match_per_call_cast(arch):
         assert toks == want
         _, held = _direct_greedy(cfg, ce.params, p, 7, slots=2, max_len=96)
         np.testing.assert_array_equal(held, rows)
+
+
+@pytest.mark.parametrize("n_rows, n_real, routed, computed", [
+    (16, 15, 15 * 8, 32 * 16),     # a decode step: 16 slots, 15 active
+    (32, 29, 29 * 8, 32 * 32),     # a prefill chunk's ragged tail
+])
+def test_serving_rows_at_published_widths(n_rows, n_real, routed, computed):
+    """Granite-3.0-1B-A400M (32 experts, top-8): every expert computes
+    the call's whole buffer, the real rows are routed to top_k each,
+    and the serving capacity is the buffer (no drop)."""
+    from repro.configs import get_config
+    from repro.models import moe
+    cfg = get_config("granite_moe_1b_a400m")
+    assert moe.serving_rows(cfg, n_rows, n_real) == (n_rows, routed, computed)
+    assert moe.serving_rows(cfg, n_rows).routed == n_rows * 8
+
+
+def _engine_spans(cfg, slots=2):
+    from repro.serving.trace import TraceSink
+    sink = TraceSink()
+    ce = ContinuousEngine(cfg, model.init_params(cfg, jax.random.PRNGKey(1)),
+                          slots=slots, max_len=96, trace=sink)
+    ce.generate(_prompts(seed=31, lens=(16, 33, 9)), max_new=6)
+    spans = {n: [r for r in sink.query(comp="engine", name=n) if r.ph == "B"]
+             for n in ("decode_step", "prefill_chunk")}
+    assert all(spans.values()), spans
+    return ce, spans
+
+
+def test_moe_spans_count_routed_and_computed_rows():
+    """A MoE engine's decode steps carry active slots x top_k x layers
+    expert-rows routed and experts x slots x layers computed; its
+    prefill chunks real tokens x top_k x layers and experts x chunk
+    size x layers."""
+    cfg = _cfg("moe")
+    L, E, k = cfg.num_layers, cfg.moe.num_experts, cfg.moe.top_k
+    ce, spans = _engine_spans(cfg)
+    for r in spans["decode_step"]:
+        assert r.attrs["moe_rows_routed"] == r.attrs["active"] * k * L
+        assert r.attrs["moe_rows_computed"] == E * ce.slots * L
+    assert {r.attrs["n"] for r in spans["prefill_chunk"]} > {32}
+    for r in spans["prefill_chunk"]:
+        assert r.attrs["moe_rows_routed"] == r.attrs["n"] * k * L
+        assert r.attrs["moe_rows_computed"] == E * ce.prefill_chunk * L
+
+
+def test_dense_spans_carry_no_moe_rows():
+    _, spans = _engine_spans(get_reduced("qwen25_0_5b"))
+    for recs in spans.values():
+        for r in recs:
+            assert not {"moe_rows_routed", "moe_rows_computed"} & set(r.attrs)
+
+
+def test_moe_ffn_scope_names_ops_and_changes_no_arithmetic(monkeypatch):
+    """The `moe_ffn` named scope shows in the paged programs' op
+    metadata, and the programs lowered without it are the same text."""
+    import contextlib
+    import re
+    cfg = _cfg("moe")
+    ce = ContinuousEngine(cfg, model.init_params(cfg, jax.random.PRNGKey(0)),
+                          slots=2, max_len=64)
+    s, w = ce.slots, ce.table_width
+
+    def lowered():
+        decode = ce._decode.lower(
+            ce.params, ce.cache, np.zeros((s, 1), np.int32),
+            np.zeros(s, np.int32), np.zeros(s, bool),
+            np.zeros((s, w), np.int32))
+        chunk = ce._chunk.lower(
+            ce.params, ce.cache, np.zeros((1, ce.prefill_chunk), np.int32),
+            np.zeros(w, np.int32), np.int32(0), np.int32(1))
+        return decode, chunk
+
+    # in op names ("moe_ffn/dot_general"); a Python frame's has no "/"
+    scope = re.compile(r'["/]moe_ffn/')
+    named = lowered()
+    for low in named:
+        assert scope.search(low.as_text(debug_info=True))
+    jax.clear_caches()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    for low, plain in zip(named, lowered()):
+        assert not scope.search(plain.as_text(debug_info=True))
+        assert low.as_text() == plain.as_text()
