@@ -90,14 +90,16 @@ def decode_attention(q, k, v, kv_len, use_pallas=True, ring=False):
     return ref.decode_attention(q, k, v, kv_len, ring=ring)
 
 
-def decode_attention_paged(q, k, v, kv_len, table, use_pallas=True):
-    """Block-table flash decode: K/V page pools [P, ps, G, dh] gathered
-    through a per-row page table [B, W] (scalar-prefetched on TPU so each
-    grid step DMAs exactly one mapped page). `kv_len` [B] masks unmapped
-    tail entries; ring callers pre-clamp it to the ring modulus."""
+def decode_attention_paged(q, new, pool, kv_len, cursor, table, layer,
+                           use_pallas=True):
+    """Block-table flash decode read in place from the layer-stacked page
+    pool [L, P, ps, lanes] through a per-row table [B, W]: each row's
+    `kv_len` earlier positions (less a ring `cursor`) plus its own token
+    `new` (scalar-prefetched on TPU, so only live pages are fetched)."""
     if use_pallas:
-        return _decode_attn_paged(q, k, v, kv_len, table)
-    return ref.decode_attention_paged(q, k, v, kv_len, table)
+        return _decode_attn_paged(q, new, pool, kv_len, cursor, table, layer)
+    return ref.decode_attention_paged(q, new, pool, kv_len, cursor, table,
+                                      layer)
 
 
 def flash_prefill(q, k, v, causal=True, window=None, use_pallas=True):

@@ -122,19 +122,40 @@ def decode_attention(q, k, v, kv_len, ring: bool = False):
     return o.reshape(B, H, dh)
 
 
-def decode_attention_paged(q, k, v, kv_len, table):
-    """Block-table decode reference. q: [B, H, dh]; k, v: [P, ps, G, dh]
-    page pools; kv_len: [B]; table: [B, W] int32 page ids (entry w backs
-    logical positions [w*ps, (w+1)*ps); unmapped tail entries are masked
-    by kv_len). Gathers each row's logical [W*ps] K/V through its table
-    and defers to the contiguous oracle."""
-    P, ps, G, dh = k.shape
-    W = table.shape[1]
+def decode_attention_paged(q, new, pool, kv_len, cursor, table, layer):
+    """Block-table decode reference, in float32. q: [B, H, dh]; new: the
+    step's own rows, (k, v) [B, G, dh] or int8 (kq, vq, ks, vs) with
+    scales [B, G]; pool: the matching pools [L, P, ps, lanes >= G*dh]
+    (scales [L, P, ps, G]); kv_len [B]: earlier positions, read from layer
+    `layer` through table [B, W] (entry w backs positions [w*ps,
+    (w+1)*ps)); cursor [B]: a position among them left out. Gathers each
+    row's logical buffer, dequantizes it, appends the step's own token
+    and takes one softmax."""
+    B, H, dh = q.shape
+    _, P, ps, _ = pool[0].shape
+    G, W = new[0].shape[1], table.shape[1]
     j = jnp.arange(W * ps)
     idx = table[:, j // ps] * ps + (j % ps)            # [B, W*ps]
-    kg = jnp.take(k.reshape(P * ps, G, dh), idx, axis=0)
-    vg = jnp.take(v.reshape(P * ps, G, dh), idx, axis=0)
-    return decode_attention(q, kg, vg, kv_len)
+
+    def gather(x):
+        x = x[layer].reshape((P * ps, -1))[:, :G * dh].astype(jnp.float32)
+        return jnp.take(x, idx, axis=0).reshape(B, W * ps, G, -1)
+
+    kv = [gather(x) for x in pool]
+    own = [x.astype(jnp.float32).reshape(B, 1, G, -1) for x in new]
+    if len(pool) == 4:                                 # int8 and scales
+        kv = [kv[0] * kv[2], kv[1] * kv[3]]
+        own = [own[0] * own[2], own[1] * own[3]]
+    k, v = (jnp.concatenate([a, o], axis=1) for a, o in zip(kv, own))
+    valid = ((j[None] < kv_len[:, None]) & (j[None] != cursor[:, None]))
+    valid = jnp.concatenate([valid, jnp.ones((B, 1), bool)], axis=1)
+    hi = jax.lax.Precision.HIGHEST
+    qg = q.astype(jnp.float32).reshape(B, G, H // G, dh)
+    s = jnp.einsum("bgnd,bsgd->bgns", qg, k, precision=hi) / jnp.sqrt(dh)
+    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bgns,bsgd->bgnd", p, v, precision=hi)
+    return o.reshape(B, H, dh)
 
 
 def flash_prefill(q, k, v, *, causal=True, window=None):

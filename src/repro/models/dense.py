@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.dist.sharding import shard
+from repro.kernels.decode_attention import decode_attention_paged
 from repro.models import layers as L
 from repro.models.common import ParamDef, attn_defs, embed_defs, mlp_defs
 
@@ -190,7 +191,8 @@ def _cache_layer(c: dict, name: str, idx):
 
 
 def _pool_gather(pool, table, ps: int):
-    """Gather a logical K/V buffer out of a page pool through a table.
+    """Gather a logical K/V buffer out of a page pool through a table
+    (chunk prefill's read; decode reads the pool in place).
 
     pool [P, ps, ...] (one layer of the pool); table [..., W] int32 page
     ids — entry w backs logical positions [w*ps, (w+1)*ps). Returns the
@@ -199,13 +201,52 @@ def _pool_gather(pool, table, ps: int):
     tail entries (callers fill them with page 0) produce junk the caller
     masks via kv_len — the gathered VALID prefix is element-for-element
     identical to what a slot-contiguous cache would hold, which is what
-    keeps paged decode bit-exact against the wave path."""
+    keeps paged prefill bit-exact against the wave path."""
     P = pool.shape[0]
     flat = pool.reshape((P * ps,) + pool.shape[2:])
     W = table.shape[-1]
     j = jnp.arange(W * ps)
     idx = table[..., j // ps] * ps + (j % ps)
     return jnp.take(flat, idx, axis=0)
+
+
+def _kv_rows(cfg: ModelConfig, k, v) -> dict:
+    """What the pool stores for new positions k, v [N, G, dh]: the values
+    themselves, or with `kv_quant` int8 values and [N, G] scales, keyed
+    by pool tensor in the order the decode kernel takes them."""
+    if cfg.kv_quant:
+        kq, ks = L.quantize_kv(k)
+        vq, vs = L.quantize_kv(v)
+        return {"k": kq, "v": vq, "k_s": ks, "v_s": vs}
+    return {"k": k, "v": v}
+
+
+def _pool_write(c: dict, idx, pg, off, rows: dict) -> None:
+    """Scatter `rows` (from `_kv_rows`) into layer `idx` of the pool at
+    (page pg[i], offset off[i]); a page id past the pool (the sentinel)
+    drops the write. K/V pages are lane-dense: a position's G heads of dh
+    are one row, zero-padded to the pool's lanes."""
+    for name, x in rows.items():
+        x = x.reshape(pg.shape[0], -1)
+        x = jnp.pad(x, ((0, 0), (0, c[name].shape[-1] - x.shape[1])))
+        c[name] = c[name].at[idx, pg, off].set(x.astype(c[name].dtype),
+                                               mode="drop")
+
+
+def _slot_kv(c: dict, idx, row, ps: int, like):
+    """One slot's logical K and V buffers [W*ps, G, dh] in `like`'s dtype,
+    gathered from layer `idx` through its table row and dequantized when
+    the pool holds int8 (`like` [.., G, dh] gives the head shape)."""
+    def get(name):
+        return _pool_gather(_cache_layer(c, name, idx), row, ps)
+
+    gd = like.shape[-2] * like.shape[-1]
+    k, v = (get(n)[:, :gd].reshape((-1,) + like.shape[-2:])
+            for n in ("k", "v"))
+    if "k_s" in c:
+        return (L.dequantize_kv(k, get("k_s"), like.dtype),
+                L.dequantize_kv(v, get("v_s"), like.dtype))
+    return k.astype(like.dtype), v.astype(like.dtype)
 
 
 def paged_attn_decode(cfg: ModelConfig, lp, y, pos, table, active, c, idx,
@@ -216,49 +257,37 @@ def paged_attn_decode(cfg: ModelConfig, lp, y, pos, table, active, c, idx,
 
     y [B,1,d] (already normed); pos [B] absolute per-slot positions;
     table [B, W] int32 page ids (this slot's mapped pages, in logical
-    order; unmapped tail entries hold page 0 and are masked by kv_len);
-    active [B] bool; c: page-pool cache dict [L, P, ps, G, dh]
-    (+ [L, P, ps, G] scales when `kv_quant`). `ring_len` > 0 marks a
-    sliding-window ring: logical position p lives at ring cursor
-    p % ring_len, and the per-row mask length is min(pos+1, ring_len)
-    (every filled ring slot is valid — position order inside the ring is
-    irrelevant because RoPE is baked into the stored keys). The new k/v
-    scatter resolves (page, offset) through the table; inactive slots
-    scatter to the out-of-bounds page sentinel and drop. Shared
-    (refcounted) prefix pages are never written here: all decode writes
-    land at positions >= the request's prompt length, which by the
-    pager's COW contract sit in slot-private pages."""
+    order; unmapped tail entries hold page 0 and are never read);
+    active [B] bool; c: page-pool cache dict (`init_page_pool`),
+    [L, P, ps, lanes] per tensor (+ [L, P, ps, G] scales when
+    `kv_quant`). `ring_len` > 0 marks a sliding-window ring: logical
+    position p lives at ring cursor p % ring_len. The new k/v scatter
+    resolves (page, offset) through the table; inactive slots scatter to
+    the out-of-bounds page sentinel and drop. Attention is the Pallas `decode_attention_paged`, which
+    reads each active slot's earlier positions in place from the pool,
+    through its table, from its first ceil(n / page_size) pages only (n
+    = pos, or min(pos, ring_len) on a ring, whose overwritten cursor is
+    left out; every filled ring slot is otherwise valid, position order
+    inside the ring being irrelevant because RoPE is baked into the
+    stored keys), and takes the step's own k/v from registers. Inactive
+    slots read no page. Shared (refcounted) prefix pages are never
+    written here: all decode writes land at positions >= the request's
+    prompt length, which by the pager's COW contract sit in slot-private
+    pages."""
     ps = page_size
     q, k, v = _qkv(cfg, lp, y, pos[:, None])
     npages = c["k"].shape[1]
     lw = pos % ring_len if ring_len else pos
     pg = jnp.take_along_axis(table, (lw // ps)[:, None], axis=1)[:, 0]
     pg = jnp.where(active, pg, npages)           # OOB sentinel -> drop
-    off = lw % ps
-    lens = jnp.minimum(pos + 1, ring_len) if ring_len else pos + 1
-    if cfg.kv_quant:
-        kq, ks = L.quantize_kv(k)
-        vq, vs = L.quantize_kv(v)
-        c["k"] = c["k"].at[idx, pg, off].set(kq[:, 0], mode="drop")
-        c["k_s"] = c["k_s"].at[idx, pg, off].set(ks[:, 0], mode="drop")
-        c["v"] = c["v"].at[idx, pg, off].set(vq[:, 0], mode="drop")
-        c["v_s"] = c["v_s"].at[idx, pg, off].set(vs[:, 0], mode="drop")
-        ctx = L.decode_attention_q8(
-            q, _pool_gather(_cache_layer(c, "k", idx), table, ps),
-            _pool_gather(_cache_layer(c, "k_s", idx), table, ps),
-            _pool_gather(_cache_layer(c, "v", idx), table, ps),
-            _pool_gather(_cache_layer(c, "v_s", idx), table, ps), lens)
-    else:
-        c["k"] = c["k"].at[idx, pg, off].set(
-            k[:, 0].astype(c["k"].dtype), mode="drop")
-        c["v"] = c["v"].at[idx, pg, off].set(
-            v[:, 0].astype(c["v"].dtype), mode="drop")
-        ctx = L.decode_attention(
-            q, _pool_gather(_cache_layer(c, "k", idx), table,
-                            ps).astype(k.dtype),
-            _pool_gather(_cache_layer(c, "v", idx), table,
-                         ps).astype(v.dtype), lens)
-    return ctx, c
+    rows = _kv_rows(cfg, k[:, 0], v[:, 0])
+    _pool_write(c, idx, pg, lw % ps, rows)
+    earlier = jnp.minimum(pos, ring_len) if ring_len else pos
+    ctx = decode_attention_paged(
+        q[:, 0], tuple(x.astype(c[n].dtype) for n, x in rows.items()),
+        tuple(c[n] for n in rows), jnp.where(active, earlier, 0), lw,
+        table, idx)
+    return ctx[:, None], c
 
 
 def paged_attn_chunk(cfg: ModelConfig, lp, y, positions, row, offset,
@@ -293,35 +322,22 @@ def paged_attn_chunk(cfg: ModelConfig, lp, y, positions, row, offset,
     npages = c["k"].shape[1]
     W = row.shape[0]
     zero = jnp.int32(0)
+    rows = _kv_rows(cfg, k[0], v[0])
     if cfg.kv_quant:
-        kq, ks = L.quantize_kv(k)
-        vq, vs = L.quantize_kv(v)
+        k_new = L.dequantize_kv(rows["k"], rows["k_s"], k.dtype)
+        v_new = L.dequantize_kv(rows["v"], rows["v_s"], v.dtype)
+    else:
+        k_new, v_new = k[0], v[0]
     p_new = offset + jnp.arange(csz)
     if ring_len:
         lw = p_new % ring_len
         dst_pg = jnp.where(p_new < limit, row[lw // ps], npages)
-        dst_off = lw % ps
         # 1. history (pre-chunk ring contents) in absolute position order
         j = jnp.arange(ring_len)
         p_hist = offset - 1 - ((offset - 1 - j) % ring_len)
         hist_dst = jnp.where(p_hist >= 0, p_hist, abs_len)   # <0 -> drop
-        if cfg.kv_quant:
-            kslot = L.dequantize_kv(
-                _pool_gather(_cache_layer(c, "k", idx), row, ps)[:ring_len],
-                _pool_gather(_cache_layer(c, "k_s", idx), row,
-                             ps)[:ring_len], k.dtype)
-            vslot = L.dequantize_kv(
-                _pool_gather(_cache_layer(c, "v", idx), row, ps)[:ring_len],
-                _pool_gather(_cache_layer(c, "v_s", idx), row,
-                             ps)[:ring_len], v.dtype)
-            k_new = L.dequantize_kv(kq, ks, k.dtype)[0]
-            v_new = L.dequantize_kv(vq, vs, v.dtype)[0]
-        else:
-            kslot = _pool_gather(_cache_layer(c, "k", idx), row,
-                                 ps)[:ring_len].astype(k.dtype)
-            vslot = _pool_gather(_cache_layer(c, "v", idx), row,
-                                 ps)[:ring_len].astype(v.dtype)
-            k_new, v_new = k[0], v[0]
+        kslot, vslot = (x[:ring_len] for x in _slot_kv(c, idx, row, ps,
+                                                       k_new))
         g, dh = kslot.shape[1], kslot.shape[2]
         kfull = jnp.zeros((abs_len, g, dh), k_new.dtype
                           ).at[hist_dst].set(kslot, mode="drop")
@@ -336,46 +352,14 @@ def paged_attn_chunk(cfg: ModelConfig, lp, y, positions, row, offset,
                           window=cfg.sliding_window, q_offset=offset,
                           kv_len=offset + csz)
         # 3. ring-write only the REAL tokens at their per-position cursors
-        if cfg.kv_quant:
-            c["k"] = c["k"].at[idx, dst_pg, dst_off].set(kq[0], mode="drop")
-            c["k_s"] = c["k_s"].at[idx, dst_pg, dst_off].set(ks[0],
-                                                             mode="drop")
-            c["v"] = c["v"].at[idx, dst_pg, dst_off].set(vq[0], mode="drop")
-            c["v_s"] = c["v_s"].at[idx, dst_pg, dst_off].set(vs[0],
-                                                             mode="drop")
-        else:
-            c["k"] = c["k"].at[idx, dst_pg, dst_off].set(
-                k[0].astype(c["k"].dtype), mode="drop")
-            c["v"] = c["v"].at[idx, dst_pg, dst_off].set(
-                v[0].astype(c["v"].dtype), mode="drop")
+        _pool_write(c, idx, dst_pg, lw % ps, rows)
         return ctx, c
     # non-ring: positions past the mapped width scatter to the sentinel
     # (a final ragged chunk's pad tail can cross the last mapped page)
     dst_pg = jnp.where(p_new // ps < W,
                        row[jnp.minimum(p_new // ps, W - 1)], npages)
-    dst_off = p_new % ps
-    if cfg.kv_quant:
-        c["k"] = c["k"].at[idx, dst_pg, dst_off].set(kq[0], mode="drop")
-        c["k_s"] = c["k_s"].at[idx, dst_pg, dst_off].set(ks[0], mode="drop")
-        c["v"] = c["v"].at[idx, dst_pg, dst_off].set(vq[0], mode="drop")
-        c["v_s"] = c["v_s"].at[idx, dst_pg, dst_off].set(vs[0], mode="drop")
-        kslot = L.dequantize_kv(
-            _pool_gather(_cache_layer(c, "k", idx), row, ps),
-            _pool_gather(_cache_layer(c, "k_s", idx), row, ps),
-            k.dtype)[None]
-        vslot = L.dequantize_kv(
-            _pool_gather(_cache_layer(c, "v", idx), row, ps),
-            _pool_gather(_cache_layer(c, "v_s", idx), row, ps),
-            v.dtype)[None]
-    else:
-        c["k"] = c["k"].at[idx, dst_pg, dst_off].set(
-            k[0].astype(c["k"].dtype), mode="drop")
-        c["v"] = c["v"].at[idx, dst_pg, dst_off].set(
-            v[0].astype(c["v"].dtype), mode="drop")
-        kslot = _pool_gather(_cache_layer(c, "k", idx), row,
-                             ps)[None].astype(k.dtype)
-        vslot = _pool_gather(_cache_layer(c, "v", idx), row,
-                             ps)[None].astype(v.dtype)
+    _pool_write(c, idx, dst_pg, p_new % ps, rows)
+    kslot, vslot = (x[None] for x in _slot_kv(c, idx, row, ps, k_new))
     ctx = L.attention(q, kslot, vslot, causal=True,
                       window=cfg.sliding_window, q_offset=offset,
                       kv_len=offset + csz)
@@ -575,18 +559,21 @@ def init_cache(cfg: ModelConfig, b: int, seq_len: int, dtype=jnp.bfloat16):
 
 def init_page_pool(cfg: ModelConfig, num_pages: int, page_size: int,
                    dtype=jnp.bfloat16):
-    """Global block-table KV pool: [L, num_pages, page_size, G, dh] per
+    """Global block-table KV pool: [L, num_pages, page_size, lanes] per
     tensor (+ [L, num_pages, page_size, G] f32 scales for `kv_quant`).
-    Pages are the pager's allocation unit — a slot maps an ordered list
-    of them through its [W] table row, and refcounted prefix pages can
-    back several slots at once (serving/pager.py)."""
+    A page is lane-dense: each position's G heads of dh are one row of
+    `lanes` = G*dh rounded up to whole 128-lane rows (zero-padded; the
+    TPU moves only lane-aligned rows, and the decode kernel DMAs whole
+    pages). Pages are the pager's allocation unit — a slot maps an
+    ordered list of them through its [W] table row, and refcounted
+    prefix pages can back several slots at once (serving/pager.py)."""
     g, hd = kv_expanded_heads(cfg), cfg.resolved_head_dim
-    shape = (cfg.num_layers, num_pages, page_size, g, hd)
+    shape = (cfg.num_layers, num_pages, page_size, -(-g * hd // 128) * 128)
     if cfg.kv_quant:
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
-                "k_s": jnp.zeros(shape[:-1], jnp.float32),
-                "v_s": jnp.zeros(shape[:-1], jnp.float32)}
+                "k_s": jnp.zeros(shape[:-1] + (g,), jnp.float32),
+                "v_s": jnp.zeros(shape[:-1] + (g,), jnp.float32)}
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 

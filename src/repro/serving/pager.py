@@ -1,7 +1,7 @@
 """Block-table KV pager: refcounted page pool + token-keyed prefix trie.
 
 The ContinuousEngine's KV cache is one global page pool
-`[L, num_pages, page_size, G, dh]` (models.init_page_pool); each slot
+`[L, num_pages, page_size, lanes]` (models.init_page_pool); each slot
 maps an ordered list of page ids through its `[W]` page-table row. This
 module owns the HOST-side bookkeeping for that pool:
 

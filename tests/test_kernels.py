@@ -297,36 +297,129 @@ def test_decode_attention_ring_clamps_per_slot():
                                np.asarray(full, np.float32))
 
 
-def test_decode_attention_paged_matches_gather_and_ref():
-    """Block-table decode: the scalar-prefetched paged kernel walks each
-    slot's page-table row directly in the pool, and must match (a) the
-    pure-jnp paged oracle and (b) gathering the logical buffer through
-    the table and running the plain kernel — including rows that share a
-    prefix page and table tail entries past kv_len (masked junk)."""
-    B, H, G, dh, P, ps, W = 3, 4, 2, 32, 8, 16, 4
-    q = jax.random.normal(k(6), (B, H, dh))
-    pool_k = jax.random.normal(k(7), (P, ps, G, dh))
-    pool_v = jax.random.normal(k(8), (P, ps, G, dh))
-    # rows 0 and 1 share page 2 as their first (prefix) page; tail
-    # entries past each row's kv_len point at junk pages
-    table = jnp.asarray([[2, 0, 1, 7],
-                         [2, 5, 7, 7],
-                         [4, 3, 6, 0]], jnp.int32)
-    lens = jnp.asarray([3 * ps, ps + 5, 2 * ps - 1], jnp.int32)
-    o_kernel = ops.decode_attention_paged(q, pool_k, pool_v, lens, table)
-    o_ref = ref.decode_attention_paged(q, pool_k, pool_v, lens, table)
-    np.testing.assert_allclose(np.asarray(o_kernel, np.float32),
+# Paged decode cases: heads, KV heads, head width, page size, table width,
+# dtype, each row's absolute position (its new token's), the ring length
+# (0: none), int8 K/V, and the rows that share their first page.
+_PAGED = {
+    "small-f32": (4, 2, 32, 16, 4, jnp.float32, [48, 21, 31], 0, False,
+                  (0, 1)),
+    # the served widths: Qwen2.5-0.5B (2 KV heads) and Granite (8), 18
+    # pages of 32; a row of length 1, one at full width, one whose new
+    # token opens a page, two sharing a prefix page
+    "qwen-bf16": (14, 2, 64, 32, 18, jnp.bfloat16, [0, 575, 40, 100, 64,
+                                                    200], 0, False, (2, 3)),
+    "granite-bf16": (16, 8, 64, 32, 18, jnp.bfloat16, [0, 575, 40, 100, 64,
+                                                       200], 0, False,
+                     (2, 3)),
+    # sliding-window ring of 64: rows inside it and rows wrapped past it
+    "ring-bf16": (14, 2, 64, 32, 2, jnp.bfloat16, [10, 63, 64, 150, 200],
+                  64, False, ()),
+    "int8": (14, 2, 64, 32, 18, jnp.bfloat16, [0, 575, 40, 100, 64, 200],
+             0, True, (2, 3)),
+}
+
+
+@pytest.mark.parametrize("case", list(_PAGED))
+def test_decode_attention_paged_matches_gather_and_ref(case):
+    """Block-table decode read in place from the layer-stacked pool: the
+    kernel walks each slot's live pages through its table and must match
+    (a) the float32 paged oracle and (b) the path it replaced, the
+    table gather (`dense._pool_gather`) plus `layers.decode_attention` /
+    `decode_attention_q8` over each row's pos + 1 positions. Table
+    tails past a row's pages hold page 0, as the engine leaves them."""
+    from repro.models import dense, layers as L
+    H, G, dh, ps, W, dtype, pos, ring, quant, shared = _PAGED[case]
+    B, layer, gd = len(pos), 1, G * dh
+    pos = jnp.asarray(pos, jnp.int32)
+    P = 1 + B * W
+    table = 1 + jnp.arange(B * W, dtype=jnp.int32).reshape(B, W)
+    if shared:
+        a, b = shared
+        table = table.at[b, 0].set(table[a, 0])
+    need = W if ring else -(-(np.asarray(pos) + 1) // ps)
+    table = jnp.where(jnp.arange(W)[None] < jnp.reshape(need, (-1, 1)),
+                      table, 0)
+    q = jax.random.normal(k(6), (B, H, dh), dtype)
+    kv = [jax.random.normal(k(7 + i), (2, P, ps, G, dh), dtype)
+          for i in range(2)]
+    own = [jax.random.normal(k(9 + i), (B, G, dh), dtype) for i in range(2)]
+    names = ("k", "v", "k_s", "v_s") if quant else ("k", "v")
+    if quant:
+        (kq, ks), (vq, vs) = (L.quantize_kv(x) for x in kv)
+        (nkq, nks), (nvq, nvs) = (L.quantize_kv(x) for x in own)
+        kv, own = [kq, vq, ks, vs], [nkq, nvq, nks, nvs]
+    # K/V pages lane-dense and padded to whole 128-lane rows, as served
+    lanes = -(-gd // 128) * 128
+    pool = {n: x if n.endswith("_s") else jnp.pad(
+        x.reshape(x.shape[:3] + (gd,)),
+        ((0, 0),) * 3 + ((0, lanes - gd),)) for n, x in zip(names, kv)}
+    new = tuple(own)
+    cursor = pos % ring if ring else pos
+    # the step's own token is in the pool at its cursor, as served
+    pg = jnp.take_along_axis(table, (cursor // ps)[:, None], axis=1)[:, 0]
+    dense._pool_write(pool, layer, pg, cursor % ps, dict(zip(names, new)))
+    pool = tuple(pool[n] for n in names)
+    earlier = jnp.minimum(pos, ring) if ring else pos
+    if not ring:   # odd rows leave out no cursor: kv_len alone bounds them
+        cursor = jnp.where(jnp.arange(B) % 2 == 1, W * ps, cursor)
+    o = ops.decode_attention_paged(q, new, pool, earlier, cursor, table,
+                                   layer)
+    o_ref = ref.decode_attention_paged(q, new, pool, earlier, cursor, table,
+                                       layer)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(o_ref, np.float32),
-                               rtol=2e-4, atol=2e-4)
-    # oracle for the oracle: per-row gather + plain decode_attention
-    flat_k = pool_k.reshape(P * ps, G, dh)
-    flat_v = pool_v.reshape(P * ps, G, dh)
-    j = jnp.arange(W * ps)
-    idx = table[:, j // ps] * ps + (j % ps)
-    o_gather = ref.decode_attention(q, jnp.take(flat_k, idx, axis=0),
-                                    jnp.take(flat_v, idx, axis=0), lens)
-    np.testing.assert_allclose(np.asarray(o_ref, np.float32),
-                               np.asarray(o_gather, np.float32))
+                               rtol=tol, atol=tol)
+    rows = [dense._pool_gather(x[layer], table, ps) for x in pool]
+    rows = [x[..., :gd].reshape(x.shape[:2] + (G, dh)) if x.shape[-1] ==
+            lanes else x for x in rows]
+    lens = jnp.minimum(pos + 1, ring) if ring else pos + 1
+    if quant:
+        o_old = L.decode_attention_q8(q[:, None], rows[0], rows[2], rows[1],
+                                      rows[3], lens)
+    else:
+        o_old = L.decode_attention(q[:, None], rows[0], rows[1], lens)
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(o_old[:, 0], np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("arch", ["qwen25_0_5b", "h2o_danube_1_8b"])
+def test_paged_decode_visits_the_pages_the_engine_counts(arch, monkeypatch):
+    """Each decode step the kernel fetches `live_pages` of every row's
+    earlier positions: summed over the slots, exactly the `pages_live`
+    the engine records on its `engine/decode_step` span (decoding slots
+    only; a sliding-window ring stops growing at its width). Prompts
+    share a prefix, and answers cross page boundaries."""
+    from repro.configs import get_reduced
+    from repro.kernels.decode_attention import live_pages
+    from repro.models import dense, model
+    from repro.serving.engine import ContinuousEngine
+    from repro.serving.trace import TraceSink
+
+    seen = []
+    served = dense.decode_attention_paged
+
+    def spy(q, new, pool, kv_len, *rest):
+        jax.debug.callback(lambda n: seen.append(np.asarray(n)), kv_len)
+        return served(q, new, pool, kv_len, *rest)
+
+    monkeypatch.setattr(dense, "decode_attention_paged", spy)
+    cfg = get_reduced(arch)
+    sink = TraceSink()
+    ce = ContinuousEngine(cfg, model.init_params(cfg, k(0)), slots=3,
+                          max_len=96, page_size=16, eos_id=-1, trace=sink)
+    rng = np.random.default_rng(3)
+    head = rng.integers(4, 500, 20).astype(np.int32)
+    prompts = [np.concatenate([head, rng.integers(4, 500, n)]).astype(
+        np.int32) for n in (13, 30)] + [rng.integers(4, 500, 70).astype(
+            np.int32)]
+    ce.generate(prompts, max_new=20)
+    live = [r.attrs["pages_live"] for r in
+            sink.query(comp="engine", name="decode_step") if r.ph == "B"]
+    visited = [int(live_pages(n, ce.page_size).sum()) for n in seen]
+    assert len(visited) == cfg.num_layers * len(live) > 0
+    assert visited == [n for n in live for _ in range(cfg.num_layers)]
 
 
 @pytest.mark.parametrize("B,H,S,dh,window", [
